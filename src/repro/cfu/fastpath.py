@@ -534,7 +534,8 @@ class FastPathExecutor:
                 x = fn(x, w)
             return x
 
-        self._jitted = jax.jit(jax.vmap(chain, in_axes=(0, None)))
+        # public so callers can ``.lower(...)`` it to inspect the program
+        self.jitted = jax.jit(jax.vmap(chain, in_axes=(0, None)))
         self.n_traces = 0          # XLA compiles once per batch shape
 
     def weights_of(self, params: Sequence) -> List[Dict[str, np.ndarray]]:
@@ -542,7 +543,7 @@ class FastPathExecutor:
 
     def __call__(self, x_q, params: Sequence) -> np.ndarray:
         x_q, batched = bind_input(x_q, self.meta)
-        y = self._jitted(x_q, self.weights_of(params))
+        y = self.jitted(x_q, self.weights_of(params))
         self.n_traces = max(self.n_traces, 1)
         out_shape = tuple(self.meta["out_shape"])
         y = np.asarray(y).reshape((x_q.shape[0],) + out_shape)

@@ -4,21 +4,30 @@ This is the paper's accelerator re-targeted at the TPU memory hierarchy
 (DESIGN.md §2). One ``pl.pallas_call`` computes an entire inverted-residual
 block; the grid iterates over *output row tiles* and, per tile:
 
-    1. streams the haloed input strip from VMEM (on-the-fly padding:
-       out-of-bounds rows/cols are replaced by the zero-point — the paper's
+    1. reads the haloed input rows straight from the VMEM-resident map
+       (on-the-fly padding: out-of-bounds rows yield the F1 zero-point and
+       the column halo is a zero-point border of the F1 strip — the paper's
        Fig. 13b address-check logic, realised as masked selects),
     2. Expansion: int8 x int8 -> int32 matmul on the MXU, requantize, ReLU6
-       (the paper's nine 8-way-MAC engines -> one MXU matmul),
+       (the paper's nine 8-way-MAC engines -> one MXU matmul per row),
     3. Depthwise: nine shifted multiply-adds on the VPU over the VMEM-
-       resident F1 strip (the paper's 9-way MAC array, No-Local-Reuse),
+       resident F1 strip (the paper's 9-way MAC array, No-Local-Reuse);
+       stride 2 decimates with strided reads of that strip,
     4. Projection: int8 matmul + requantize, output-stationary in VMEM
-       (the paper's 56 OS accumulator engines -> one MXU matmul tile).
+       (the paper's 56 OS accumulator engines -> one MXU matmul per row).
 
 The intermediate feature maps F1/F2 exist ONLY inside this kernel's VMEM
-registers for the lifetime of one grid step — they are never written to HBM.
-That is the zero-buffer property; XLA's layer-by-layer lowering of the
-reference implementation materializes both (benchmarks/bench_traffic.py
-shows the byte difference).
+(a per-tile scratch strip and registers) for the lifetime of one grid step —
+they are never written to HBM. That is the zero-buffer property; XLA's
+layer-by-layer lowering of the reference implementation materializes both
+(benchmarks/bench_traffic.py shows the byte difference).
+
+Mosaic (the TPU kernel compiler) constrains the formulation: the input map
+is indexed as a ref (a loaded value cannot be dynamically sliced), every
+matmul works on one (W, C) row (int8 reshapes of narrow maps such as
+(6, 10, 24) -> (60, 24) have no vector layout), and the depthwise taps are
+read back from the F1 scratch with ``pl.ds`` strides (a strided slice of a
+value is refused for stride 2).
 
 Granularity note (hardware adaptation): the paper computes one output PIXEL
 per pipeline beat because its F1 storage is a 3x3xM register file. VMEM is
@@ -41,81 +50,84 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 INT8_MIN, INT8_MAX = -128, 127
+LANES = 128
 
 
-def _requant(acc_i32, m_ref, zp_out: int, lo: int, hi: int):
+def _requant(acc_i32, m, zp_out: int, lo: int, hi: int):
     """int32 accumulator -> int8, float-multiplier requantization.
 
     Identical arithmetic to core.quant.requantize so kernel output is
     bit-identical to the pure-JAX disciplines.
     """
-    y = jnp.round(acc_i32.astype(jnp.float32) * m_ref)
+    y = jnp.round(acc_i32.astype(jnp.float32) * m)
     y = y.astype(jnp.int32) + zp_out
     return jnp.clip(y, lo, hi).astype(jnp.int8)
+
+
+def _matmul_i32(a_i8, w_i8):
+    return jax.lax.dot_general(a_i8, w_i8, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.int32)
 
 
 def _fused_dsc_kernel(
     x_ref, w_exp_ref, w_dw_ref, w_proj_ref,
     b_exp_ref, b_dw_ref, b_proj_ref,
     m_exp_ref, m_dw_ref, m_proj_ref,
-    out_ref,
-    *, h: int, w: int, cin: int, cmid: int, cout: int,
+    out_ref, f1_ref,
+    *, h: int, w: int,
     stride: int, tile_rows: int,
-    zp_in: int, zp_f1: int, zp_f2: int, zp_out: int,
+    zp_f1: int, zp_f2: int, zp_out: int,
     q6_f1: int, q6_f2: int,
 ):
     t = pl.program_id(0)
     s, k = stride, 3
     w2 = -(-w // s)
-    in_rows = (tile_rows - 1) * s + k
+    n_chunks, in_rows = f1_ref.shape[0], f1_ref.shape[1]
     r0 = t * tile_rows * s - 1  # first input row incl. top halo (may be -1)
 
-    x = x_ref[...]  # (H, W, C) int8, VMEM-resident (TinyML-sized maps)
+    # ---- column halo: zero-point border of the F1 strip (Fig. 13b) ---------
+    zcol = jnp.full((n_chunks, in_rows, 1, LANES), zp_f1, jnp.int32)
+    f1_ref[:, :, 0:1, :] = zcol
+    f1_ref[:, :, w + 1:w + 2, :] = zcol
 
-    # ---- on-the-fly padded input strip (Fig. 13b) --------------------------
-    rows = []
+    # ---- Expansion stage: MXU int8 matmul + requant + ReLU6, per row -------
+    w_exp = w_exp_ref[...]
+    b_exp, m_exp = b_exp_ref[...], m_exp_ref[...]
     for i in range(in_rows):           # unrolled: in_rows is small & static
         r = r0 + i
-        row = jax.lax.dynamic_index_in_dim(x, jnp.clip(r, 0, h - 1), axis=0,
-                                           keepdims=False)       # (W, C)
-        valid = jnp.logical_and(r >= 0, r < h)
-        rows.append(jnp.where(valid, row, jnp.int8(zp_in)))
-    strip = jnp.stack(rows, axis=0)    # (in_rows, W, C)
+        row = x_ref[jnp.clip(r, 0, h - 1)]                       # (W, C)
+        f1 = _requant(_matmul_i32(row, w_exp) + b_exp, m_exp,
+                      zp_f1, zp_f1, q6_f1)
+        f1 = jnp.where(jnp.logical_and(r >= 0, r < h),
+                       f1.astype(jnp.int32), zp_f1)
+        for c in range(n_chunks):
+            f1_ref[c, i, 1:w + 1, :] = f1[:, c * LANES:(c + 1) * LANES]
 
-    # ---- Expansion stage: MXU int8 matmul + requant + ReLU6 ----------------
-    acc = jax.lax.dot_general(
-        strip.reshape(in_rows * w, cin), w_exp_ref[...],
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
-    acc = acc + b_exp_ref[...]
-    f1 = _requant(acc, m_exp_ref[...], zp_f1, zp_f1, q6_f1)
-    f1 = f1.reshape(in_rows, w, cmid)
-
-    # ---- column halo: VMEM-local pad with the F1 zero-point ----------------
-    # (the TPU analogue of the address-check mux; never touches HBM)
-    zcol = jnp.full((in_rows, 1, cmid), zp_f1, jnp.int8)
-    f1p = jnp.concatenate([zcol, f1, zcol], axis=1)  # (in_rows, W+2, M)
-
-    # ---- Depthwise stage: nine shifted VPU multiply-adds (NLR) -------------
-    acc2 = jnp.zeros((tile_rows, w2, cmid), jnp.int32)
-    for dy in range(k):
-        for dx in range(k):
-            tap = jax.lax.slice(
-                f1p, (dy, dx, 0),
-                (dy + (tile_rows - 1) * s + 1, dx + (w2 - 1) * s + 1, cmid),
-                (s, s, 1)).astype(jnp.int32)
-            acc2 = acc2 + tap * w_dw_ref[dy * k + dx, :].astype(jnp.int32)
-    acc2 = acc2 + b_dw_ref[...]
-    f2 = _requant(acc2, m_dw_ref[...], zp_f2, zp_f2, q6_f2)
-
-    # ---- Projection stage: MXU int8 matmul, output-stationary --------------
-    acc3 = jax.lax.dot_general(
-        f2.reshape(tile_rows * w2, cmid), w_proj_ref[...],
-        (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32)
-    acc3 = acc3 + b_proj_ref[...]
-    y = _requant(acc3, m_proj_ref[...], zp_out, INT8_MIN, INT8_MAX)
-    out_ref[...] = y.reshape(tile_rows, w2, cout)
+    # ---- Depthwise (VPU, nine taps) + Projection (MXU), per output row -----
+    w_dw = w_dw_ref[...].astype(jnp.int32)                    # (9, Mp)
+    b_dw, m_dw = b_dw_ref[...], m_dw_ref[...]
+    w_proj = w_proj_ref[...]
+    b_proj, m_proj = b_proj_ref[...], m_proj_ref[...]
+    for oy in range(tile_rows):
+        chunks = []
+        for c in range(n_chunks):
+            acc2 = jnp.zeros((w2, LANES), jnp.int32)
+            for dy in range(k):
+                for dx in range(k):
+                    cols = pl.ds(dx, w2, stride=s) if s > 1 else \
+                        pl.ds(dx, w2)
+                    tap = f1_ref[c, oy * s + dy, cols, :]
+                    wt = w_dw[dy * k + dx:dy * k + dx + 1,
+                              c * LANES:(c + 1) * LANES]
+                    acc2 = acc2 + tap * wt
+            chunks.append(acc2)
+        acc2 = chunks[0] if n_chunks == 1 else jnp.concatenate(chunks, 1)
+        f2 = _requant(acc2 + b_dw, m_dw, zp_f2, zp_f2, q6_f2)
+        acc3 = _matmul_i32(f2, w_proj) + b_proj
+        out_ref[oy] = _requant(acc3, m_proj, zp_out, INT8_MIN, INT8_MAX)
 
 
 def fused_dsc_pallas(
@@ -130,6 +142,8 @@ def fused_dsc_pallas(
       w_exp: (C, M) int8. w_dw9: (9, M) int8, tap-major. w_proj: (M, N) int8.
       b_*: int32 biases (zero-point folded). m_*: float32 requant multipliers.
       zps: (zp_in, zp_f1, zp_f2, zp_out). q6: quantized ReLU6 caps (f1, f2).
+        zp_in is not read: out-of-map rows and columns are filled in the F1
+        domain, where the reference pads.
       tile_rows: output rows computed per grid step (VMEM working-set knob).
     Returns: (H2, W2, N) int8.
     """
@@ -139,36 +153,54 @@ def fused_dsc_pallas(
     h2, w2 = -(-h // stride), -(-w // stride)
     # Keep the requested tile granularity even when it doesn't divide h2:
     # run ceil(h2/tile_rows) grid steps over a row-padded output and slice
-    # the valid rows off afterwards. The kernel already clips + masks
-    # out-of-range input rows to the zero point, so the overhang tile
-    # computes discardable rows instead of reading out of bounds. (The old
-    # fallback silently degraded to the largest divisor of h2 — tile_rows=1
-    # for prime h2, i.e. one grid step per output row.)
+    # the valid rows off afterwards. The kernel clips + masks out-of-range
+    # input rows, so the overhang tile computes discardable rows instead of
+    # reading out of bounds.
     tile_rows = min(tile_rows, h2)
     n_tiles = -(-h2 // tile_rows)
     h2p = n_tiles * tile_rows
-    grid = (n_tiles,)
+    in_rows = (tile_rows - 1) * stride + 3
+
+    # Mosaic's strided loads want a buffer whose last dim is exactly one
+    # 128-lane vreg row, so the F1 strip is kept as whole 128-channel
+    # chunks. The expanded channels are zero-padded up to that width: a
+    # padded channel has zero weights, bias and multiplier, so its F1/F2
+    # values are the zero points and its projection weights are zero —
+    # the block's output is unchanged.
+    n_chunks = -(-cmid // LANES)
+    pad = n_chunks * LANES - cmid
+    w_exp = jnp.pad(w_exp, ((0, 0), (0, pad)))
+    w_dw9 = jnp.pad(w_dw9, ((0, 0), (0, pad)))
+    w_proj = jnp.pad(w_proj, ((0, pad), (0, 0)))
+    # per-channel vectors as (1, n) rows, broadcast over a (W, n) row
+    vecs = [jnp.pad(v, (0, pad)).reshape(1, -1)
+            for v in (b_exp, b_dw, m_exp, m_dw)]
+    b_exp, b_dw, m_exp, m_dw = vecs
+    b_proj, m_proj = b_proj.reshape(1, -1), m_proj.reshape(1, -1)
+    cmid_p = n_chunks * LANES
 
     kernel = functools.partial(
-        _fused_dsc_kernel, h=h, w=w, cin=cin, cmid=cmid, cout=cout,
-        stride=stride, tile_rows=tile_rows,
-        zp_in=zps[0], zp_f1=zps[1], zp_f2=zps[2], zp_out=zps[3],
+        _fused_dsc_kernel, h=h, w=w, stride=stride, tile_rows=tile_rows,
+        zp_f1=zps[1], zp_f2=zps[2], zp_out=zps[3],
         q6_f1=q6[0], q6_f2=q6[1])
 
     whole = lambda shape: pl.BlockSpec(shape, lambda t: (0,) * len(shape))
     y = pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(n_tiles,),
         in_specs=[
             whole((h, w, cin)),          # x: whole map stays in VMEM
-            whole((cin, cmid)),          # w_exp (broadcast, like Fig. 11)
-            whole((9, cmid)),            # w_dw nine-bank layout (Fig. 12)
-            whole((cmid, cout)),         # w_proj (per-engine LUTRAM, Fig. 8)
-            whole((cmid,)), whole((cmid,)), whole((cout,)),
-            whole((cmid,)), whole((cmid,)), whole((cout,)),
+            whole((cin, cmid_p)),        # w_exp (broadcast, like Fig. 11)
+            whole((9, cmid_p)),          # w_dw nine-bank layout (Fig. 12)
+            whole((cmid_p, cout)),       # w_proj (per-engine LUTRAM, Fig. 8)
+            whole((1, cmid_p)), whole((1, cmid_p)), whole((1, cout)),
+            whole((1, cmid_p)), whole((1, cmid_p)), whole((1, cout)),
         ],
         out_specs=pl.BlockSpec((tile_rows, w2, cout), lambda t: (t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((h2p, w2, cout), jnp.int8),
+        # the haloed F1 strip of one tile (int32: the depthwise operand)
+        scratch_shapes=[pltpu.VMEM((n_chunks, in_rows, w + 2, LANES),
+                                   jnp.int32)],
         interpret=interpret,
     )(x_q, w_exp, w_dw9, w_proj, b_exp, b_dw, b_proj, m_exp, m_dw, m_proj)
     return y if h2p == h2 else y[:h2]

@@ -31,8 +31,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
-
 _ACTS = {
     "silu": lambda x: x * jax.nn.sigmoid(x),
     "gelu": lambda x: jax.nn.gelu(x, approximate=True),
@@ -105,7 +103,7 @@ def fused_ffn_pallas(x, w_gate, w_up, w_down, *, act: str = "silu",
         out_specs=pl.BlockSpec((block_t, d), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t, d), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_t, d), jnp.float32)],  # OS accumulator
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*args)

@@ -2,10 +2,10 @@
 instructions" of the JAX world (the analogue of the paper's CFU R-type
 interface: one call per fused block).
 
-On this CPU container the kernels run with interpret=True (Pallas executes
-the kernel body in Python); on TPU, set interpret=False (default resolves
-via ``default_interpret()``). Model code calls these wrappers, never the
-kernels directly.
+On the CPU the kernels run with interpret=True (Pallas executes the kernel
+body in Python); on a TPU they are compiled by Mosaic (interpret=False).
+The default resolves via ``default_interpret()``. Model code calls these
+wrappers, never the kernels directly.
 """
 
 from __future__ import annotations

@@ -80,6 +80,7 @@ from repro.cfu.trace import Tracer
 from repro.configs.vww import VWW
 from repro.core import dsc, quant
 from repro.core.fusion import Schedule, modeled_cycles, run_block
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _single_block(key, name: str):
@@ -508,6 +509,7 @@ def main(argv=None):
         if args.backend == "fast":
             raise SystemExit("--fault needs --backend golden")
 
+    enable_compile_cache()
     key = jax.random.PRNGKey(args.seed)
     pe = _parse_pe(args.pe)
     schedules = (schedule_names() if args.schedule == "all"

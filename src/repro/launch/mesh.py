@@ -17,21 +17,11 @@ data parallelism (one gradient all-reduce per step over DCN).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5: explicit axis types on every mesh
-    from jax.sharding import AxisType
-except (ImportError, AttributeError):  # jax 0.4.x: implicit (Auto) axes only
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape, names) -> Mesh:
-    """``jax.make_mesh`` with Auto axis types where the release supports
-    them. Older jax (0.4.x) has neither ``AxisType`` nor the ``axis_types``
-    kwarg — every axis is implicitly Auto there, so plain make_mesh is the
-    same mesh."""
-    if AxisType is None:
-        return jax.make_mesh(tuple(shape), tuple(names))
+    """``jax.make_mesh`` with every axis typed Auto."""
     return jax.make_mesh(tuple(shape), tuple(names),
                          axis_types=(AxisType.Auto,) * len(names))
 

@@ -51,6 +51,7 @@ from repro.cfu.serve.policies import POLICIES
 from repro.cfu.serve.report import (curve_table, doctor_lines,
                                     frontier_table, summary_lines)
 from repro.configs.vww import VWW
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def _parse_pe(text):
@@ -179,6 +180,7 @@ def main(argv=None):
                     help="write the result payload to this path")
     args = ap.parse_args(argv)
 
+    enable_compile_cache()
     freq_hz = args.freq_mhz * 1e6
     slo_cycles = args.slo_ms * 1e-3 * freq_hz
     service = build_vww_service(

@@ -70,6 +70,16 @@ def block_specs() -> List[Tuple[str, DSCBlockSpec]]:
             for name, ci, cm, co, s in PAPER_BLOCKS]
 
 
+def block_input_hw(img_hw: int = 80) -> List[int]:
+    """Input feature-map size of each PAPER_BLOCKS entry for an
+    ``img_hw`` image (the stride-2 stem halves it first)."""
+    hw, out = -(-img_hw // 2), []
+    for _, spec in block_specs():
+        out.append(hw)
+        hw = spec.out_hw(hw, hw)[0]
+    return out
+
+
 def init_and_quantize(key, *, img_hw: int = 80, head_ch: int = 128,
                       n_classes: int = 2) -> MobileNetV2Params:
     """Random float network -> post-training int8 quantization (TFLite

@@ -16,6 +16,7 @@ from repro.kernels import ops, ref
 from repro.kernels.fused_dsc import fused_dsc_pallas
 from repro.kernels.fused_ffn import fused_ffn_pallas
 from repro.kernels.flash_attention import flash_attention
+from repro.models import mobilenetv2 as mnv2
 
 
 # --- fused DSC --------------------------------------------------------------
@@ -53,6 +54,33 @@ def test_fused_dsc_exact_vs_oracle(spec, hw, tile_rows):
                              qp.m_proj, stride=spec.stride, zps=zps,
                              q6=(qp.q6_f1, qp.q6_f2))
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+VWW_BLOCKS = [(name, spec, hw) for (name, spec), hw
+              in zip(mnv2.block_specs(), mnv2.block_input_hw(80))]
+
+
+@pytest.mark.parametrize("name,spec,hw", VWW_BLOCKS,
+                         ids=[b[0] for b in VWW_BLOCKS])
+def test_fused_dsc_vww_blocks_exact_vs_reference(name, spec, hw):
+    """Every VWW block at its real widths and map size (stride 2 and the
+    10x10 / 5x5 maps included), held to the layer-by-layer reference."""
+    p32 = dsc.init_dsc_block_f32(jax.random.PRNGKey(3), spec)
+    calib = np.asarray(jax.random.normal(jax.random.PRNGKey(4),
+                                         (hw, hw, spec.cin)))
+    qp = dsc.quantize_dsc_block(p32, spec, calib)
+    x_q = jnp.asarray(quant.quantize(calib, qp.qp_in))
+    zps = (qp.qp_in.zero_point, qp.qp_f1.zero_point,
+           qp.qp_f2.zero_point, qp.qp_out.zero_point)
+    got = fused_dsc_pallas(x_q, qp.w_exp, qp.w_dw.reshape(9, spec.cmid),
+                           qp.w_proj, qp.b_exp, qp.b_dw, qp.b_proj, qp.m_exp,
+                           qp.m_dw, qp.m_proj, stride=spec.stride, zps=zps,
+                           q6=(qp.q6_f1, qp.q6_f2), tile_rows=4,
+                           interpret=True)
+    if spec.has_residual:
+        got = dsc.residual_add_q(got, x_q, qp)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(dsc.dsc_block_reference(x_q, qp)))
 
 
 # --- fused FFN --------------------------------------------------------------

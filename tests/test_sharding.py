@@ -10,8 +10,8 @@ from repro.configs import registry
 from repro.models import lm
 from repro.runtime import sharding as shd
 
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH_MP = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH_MP = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 @pytest.mark.parametrize("name", list(registry.ARCH_NAMES))
