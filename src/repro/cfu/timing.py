@@ -757,8 +757,11 @@ class BatchCostModel:
         expression ``report`` sums — so the emitted spans add up to
         ``total_cycles`` with no rounding slack (the exactness invariant
         tests/test_cfu_trace.py pins). Cumulative byte counters ride the
-        same timeline; returns the end timestamp so callers can stack
-        streams end-to-end. Tracing never feeds back into the report.
+        same timeline. Returns the end timestamp, ``t0`` plus the same
+        ``sum()`` over the same durations that ``report`` totals (the
+        running ``+=`` of the span starts can differ from it in the last
+        bit), so callers can stack streams end-to-end. Tracing never feeds
+        back into the report.
         """
         w = self._w
         b = float(batch)
@@ -789,15 +792,16 @@ class BatchCostModel:
                       "weight_bytes": p.weight_bytes})
             t += dur
             tracer.counter("model.bytes", t, dict(cum), pid=pid)
+        end = t0 + sum(self._phase_cycles(p, b) for p in w.phases)
         # per-boundary handoff cost as a counter track (satellite: the
         # ROADMAP's calibration hook made visible)
-        tracer.counter("model.handoff_cycles", t,
+        tracer.counter("model.handoff_cycles", end,
                        {"per_round": self.handoff_sync_cycles
                         * len(w.dbuf_bases),
                         "n_boundaries": len(w.dbuf_bases)}, pid=pid)
         rep = self.report(batch)
-        tracer.counter_bank(rep.counter_bank(), t, pid=pid)
-        return t
+        tracer.counter_bank(rep.counter_bank(), end, pid=pid)
+        return end
 
 
 class MultiStreamCostModel:
