@@ -73,7 +73,10 @@ two weight sets with the same quantization domains share one trace
 (weights are traced arguments), while a different calibration re-traces
 instead of silently reusing stale constants. Under ``jax.jit`` each new
 batch *shape* compiles once more from the same trace; the Python-level
-lift + stage composition is never repeated.
+lift + stage composition is never repeated. The weights, being traced
+arguments, stay on the device between calls: an executor keeps the last
+set it was called with bound to device arrays and re-binds only what
+changed (``FastPathExecutor``), so a weight is uploaded once, not per call.
 
 Multi-stream programs lift to the sequential composition of their
 segments: the frame pipeline changes *when* a core computes, never what;
@@ -486,11 +489,6 @@ def _build_stage_fn(stage: _Stage, p, use_pallas: bool):
 _DTYPES = {"w": np.int8, "b": np.int32, "m": np.float32}   # by name prefix
 
 
-def _stage_weights(stage: _Stage, p) -> Dict[str, np.ndarray]:
-    return {name: np.asarray(getattr(p, name), _DTYPES[name[0]])
-            for name in _STAGE_ARRAYS[stage.kind]}
-
-
 # --------------------------------------------------------------------------
 # The executor object + fingerprint cache
 # --------------------------------------------------------------------------
@@ -500,28 +498,45 @@ class FastPathExecutor:
     """One lifted + traced program; ``__call__`` matches ``run_program`` /
     ``run_multistream`` (minus stats/tracer — the interpreter owns those).
 
+    The stage arrays stay on the device between calls. Each call binds the
+    params it is given to device arrays, per stage array and by what the
+    caller passed:
+
+    * a ``jax.Array`` of the stage's dtype on the executor's ``device``
+      is handed to the chain as it is: no copy back, no upload;
+    * any other ``jax.Array`` is moved and cast on the device once, and
+      the result reused while the caller passes the same object (the
+      executor holds it, so its identity stays unique; it is immutable);
+    * a host array is uploaded once and reused while its contents equal a
+      host snapshot taken at the upload (``np.array_equal`` each call), so
+      a weight changed in place is uploaded again.
+
+    Only the last bound set is kept: calling with another weight set of
+    the same quantization constants (the executor ``fast_executor``
+    shares between them) re-binds, and the old device copies are freed.
+    The stage arrays are never donated; only the input is.
+    ``weight_uploads`` counts the arrays uploaded or converted over the
+    executor's life, ``weight_binds`` the calls that had any.
+
     Each call runs four phases, each inside a ``jax.profiler``
     annotation on the profiler's clock (the clock of the device's
     ``XLA Ops``), once per call and in this order:
 
-    * ``fastpath.weights``: ``weights_of`` rebuilds the stage arrays,
-      copying back those that live on the device (arg ``d2h_arrays``);
+    * ``fastpath.weights``: the stage arrays bound as above, without
+      uploading (args ``d2h_arrays``, always 0, ``reused`` and
+      ``uploaded``, the arrays this call uploads or converts);
     * ``fastpath.put_input``: the input checked and uploaded (``bytes``);
-    * ``fastpath.launch``: the jitted chain dispatched, with the stage
-      arrays uploaded inside it by jit's own argument path (``arrays``,
-      ``bytes``);
+    * ``fastpath.launch``: the uploads the binding asked for, if any
+      (``arrays``, ``bytes``; 0 once bound), then the jitted chain
+      dispatched;
     * ``fastpath.readback``: the logits copied back, which waits for the
       device.
 
-    Both uploads take jit's argument path, the cheapest on the host: the
-    input through a donated identity ``jit`` (its output is the uploaded
-    buffer, no device copy), the stage arrays inside the chain's call. On
-    a TPU v5e ``jax.device_put`` of the input costs about 0.2 ms more a
-    call, and any separate upload of the 72 stage arrays 1 to 5 ms more.
-    Each dispatch that compiles, those of the first call with a new input
-    shape, runs inside a nested ``fastpath.compile``. The args are worked
-    out once per input shape, at its first call. With no profiler running
-    an annotation costs about a microsecond.
+    The input goes up through a donated identity ``jit``, which on a TPU
+    v5e costs about 0.2 ms a call less than ``jax.device_put``. Each
+    dispatch that compiles, those of the first call with a new input
+    shape, runs inside a nested ``fastpath.compile``. With no profiler
+    running an annotation costs about a microsecond.
     """
 
     def __init__(self, prog, params: Sequence,
@@ -563,7 +578,15 @@ class FastPathExecutor:
         self.jitted = jax.jit(jax.vmap(chain, in_axes=(0, None)))
         self._put = jax.jit(lambda x: x, donate_argnums=0)
         self._shapes: set = set()       # input shapes launched so far
-        self._span_args: Dict[Tuple, Dict[str, Dict[str, int]]] = {}
+        self.device = jax.devices()[0]
+        # the stage arrays in chain order: (params index, field, dtype)
+        self._slots = [(st.block, name, np.dtype(_DTYPES[name[0]]))
+                       for st in self.stages
+                       for name in _STAGE_ARRAYS[st.kind]]
+        # per slot, the last bound (source, host snapshot, device array)
+        self._bound: List[Optional[Tuple]] = [None] * len(self._slots)
+        self.weight_uploads = 0
+        self.weight_binds = 0
 
     @property
     def n_traces(self) -> int:
@@ -580,39 +603,85 @@ class FastPathExecutor:
         with jax.profiler.TraceAnnotation("fastpath.compile"):
             return fn(*args)
 
-    def weights_of(self, params: Sequence) -> List[Dict[str, np.ndarray]]:
-        return [_stage_weights(st, params[st.block]) for st in self.stages]
+    def _plan(self, params: Sequence) -> Tuple[list, List[int]]:
+        """The stage arrays flat in chain order, each its device copy where
+        one is bound, and the slots that still need an upload (left as
+        the caller's array)."""
+        import jax
+        flat, todo = [], []
+        for k, (block, name, dt) in enumerate(self._slots):
+            v = getattr(params[block], name)
+            held = self._bound[k]
+            if held is not None and held[0] is v:     # bound before
+                v = held[2]
+            elif isinstance(v, jax.Array):
+                if v.dtype == dt and v.devices() == {self.device}:
+                    self._bound[k] = (v, None, v)
+                else:
+                    todo.append(k)
+            else:
+                v = np.asarray(v)
+                if (held is not None and held[1] is not None
+                        and np.array_equal(held[1], v)):
+                    v = held[2]
+                else:
+                    todo.append(k)
+            flat.append(v)
+        return flat, todo
 
-    def _args_of(self, in_shape: Tuple, params: Sequence
-                 ) -> Dict[str, Dict[str, int]]:
-        """The spans' args for calls with an input of this shape."""
-        args = self._span_args.get(in_shape)
-        if args is None:
-            import jax
-            vals = [(getattr(params[st.block], name), _DTYPES[name[0]])
-                    for st in self.stages for name in _STAGE_ARRAYS[st.kind]]
-            args = self._span_args[in_shape] = {
-                "fastpath.weights": {"d2h_arrays": sum(
-                    isinstance(v, jax.Array) for v, _ in vals)},
-                "fastpath.put_input": {"bytes": int(np.prod(in_shape))},
-                "fastpath.launch": {"arrays": len(vals), "bytes": sum(
-                    int(np.size(v)) * np.dtype(dt).itemsize
-                    for v, dt in vals)},
-            }
-        return args
+    def _upload(self, flat: list, todo: List[int]) -> int:
+        """Binds the slots in ``todo`` to device copies, in place in
+        ``flat``; returns the bytes put on the device."""
+        if not todo:
+            return 0
+        import jax
+        n_bytes = 0
+        for k in todo:
+            v, dt = flat[k], self._slots[k][2]
+            if isinstance(v, jax.Array):
+                dev = jax.device_put(v, self.device).astype(dt)
+                self._bound[k] = (v, None, dev)
+            else:
+                # the upload reads the executor's own copy, never the
+                # caller's buffer, which may change in place
+                snap = np.array(v)
+                dev = jax.device_put(snap.astype(dt, copy=False),
+                                     self.device)
+                self._bound[k] = (None, snap, dev)
+            flat[k] = dev
+            n_bytes += dev.nbytes
+        self.weight_uploads += len(todo)
+        self.weight_binds += 1
+        return n_bytes
+
+    def _nest(self, flat: list) -> List[Dict[str, object]]:
+        it = iter(flat)
+        return [{name: next(it) for name in _STAGE_ARRAYS[st.kind]}
+                for st in self.stages]
+
+    def weights_of(self, params: Sequence) -> List[Dict[str, object]]:
+        """The stage arrays as the chain takes them (per stage, by field
+        name), bound on the device the way ``__call__`` binds them."""
+        flat, todo = self._plan(params)
+        self._upload(flat, todo)
+        return self._nest(flat)
 
     def __call__(self, x_q, params: Sequence) -> np.ndarray:
         import jax
         span = jax.profiler.TraceAnnotation
-        args = self._args_of(np.shape(x_q), params)
-        with span("fastpath.weights", **args["fastpath.weights"]):
-            weights = self.weights_of(params)
-        with span("fastpath.put_input", **args["fastpath.put_input"]):
+        with span("fastpath.weights") as s:
+            flat, todo = self._plan(params)
+            s.set_metadata(d2h_arrays=0, reused=len(flat) - len(todo),
+                           uploaded=len(todo))
+        with span("fastpath.put_input") as s:
             x_q, batched = bind_input(x_q, self.meta)
+            s.set_metadata(bytes=x_q.nbytes)
             new = x_q.shape not in self._shapes
             x_dev = self._dispatch(self._put, new, x_q)
-        with span("fastpath.launch", **args["fastpath.launch"]):
-            y = self._dispatch(self.jitted, new, x_dev, weights)
+        with span("fastpath.launch") as s:
+            s.set_metadata(arrays=len(todo),
+                           bytes=self._upload(flat, todo))
+            y = self._dispatch(self.jitted, new, x_dev, self._nest(flat))
             self._shapes.add(x_q.shape)
         with span("fastpath.readback"):
             out_shape = tuple(self.meta["out_shape"])

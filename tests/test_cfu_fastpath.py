@@ -244,6 +244,106 @@ def test_cache_lru_eviction_and_bit_exact_retrace():
     assert fastpath.cache_info()["limit"] == fastpath._DEFAULT_CACHE_LIMIT
 
 
+# --- the stage arrays stay on the device between calls -----------------------
+
+
+def _stage_arrays_as(params, kind):
+    """The chain's params with every stage array as ``kind``: the
+    fixture's own device arrays, host copies, or device arrays whose
+    weights are int32 instead of the stage's int8."""
+    def conv(name, v):
+        if kind == "host":
+            return np.array(v)
+        if kind == "wrong_dtype" and name.startswith("w"):
+            return jax.numpy.asarray(v, jax.numpy.int32)
+        return v
+    return [dataclasses.replace(p, **{n: conv(n, getattr(p, n))
+                                      for n in fastpath._STAGE_ARRAYS["dsc"]})
+            for p in params]
+
+
+def _one_step_off(a, index):
+    """A copy of int8 ``a`` with one element one step away."""
+    a = np.array(a)
+    a[index] = a[index] + 1 if a[index] < 127 else a[index] - 1
+    return a
+
+
+# arrays the first call puts on the device, by what the caller passes:
+# device arrays of the stage's dtype none, host arrays all, int32 device
+# weights their three per block (converted on the device)
+RESIDENT = {"device": 0, "host": 9 * len(CHAIN), "wrong_dtype": 3 * len(CHAIN)}
+
+
+@pytest.mark.parametrize("kind", sorted(RESIDENT))
+def test_stage_arrays_bound_once_then_reused(kind):
+    specs, params, x_q = _chain_fixture()
+    params = _stage_arrays_as(params, kind)
+    prog = compile_network(specs, HW, HW, "fused")
+    ex = fastpath.FastPathExecutor(prog, params)
+    passed, jitted = [], ex.jitted
+
+    def spy(x, wlist):
+        passed.append(wlist)
+        return jitted(x, wlist)
+    ex.jitted = spy
+    ref = run_program(prog, x_q, params)
+    for _ in range(2):
+        np.testing.assert_array_equal(ex(x_q, params), ref)
+    # the second call uploads and converts nothing
+    assert ex.weight_uploads == RESIDENT[kind]
+    assert ex.weight_binds == int(RESIDENT[kind] > 0)
+    first, second = passed
+    for st, w1, w2 in zip(ex.stages, first, second):
+        for name, a in w1.items():
+            src = getattr(params[st.block], name)
+            assert isinstance(a, jax.Array) and a.devices() == {ex.device}
+            assert a.dtype == fastpath._DTYPES[name[0]], name
+            # a device array of the stage's dtype reaches the chain as the
+            # caller's own object: never copied back, never uploaded
+            assert (a is src) == (isinstance(src, jax.Array)
+                                  and src.dtype == a.dtype), name
+            assert w2[name] is a, name
+
+
+def test_host_stage_array_changed_in_place_is_uploaded_again():
+    specs, params, x_q = _chain_fixture()
+    params = _stage_arrays_as(params, "host")
+    prog = compile_network(specs, HW, HW, "fused")
+    ex = fastpath.FastPathExecutor(prog, params)
+    y0 = ex(x_q, params)
+    np.testing.assert_array_equal(y0, run_program(prog, x_q, params))
+    uploads = ex.weight_uploads
+    params[0].w_exp[...] = _one_step_off(params[0].w_exp, (0, 0))
+    ref = run_program(prog, x_q, params)
+    assert not np.array_equal(ref, y0)
+    np.testing.assert_array_equal(ex(x_q, params), ref)
+    assert ex.weight_uploads == uploads + 1         # that array alone
+    # a fresh params list with equal contents reuses every upload
+    np.testing.assert_array_equal(
+        ex(x_q, _stage_arrays_as(params, "host")), ref)
+    assert ex.weight_uploads == uploads + 1
+
+
+def test_alternating_weight_sets_on_a_shared_executor():
+    """Two weight sets of the same quantization constants share one
+    executor: each switch re-binds what differs and stays bit-exact."""
+    specs, params, x_q = _chain_fixture()
+    prog = compile_network(specs, HW, HW, "fused")
+    a = _stage_arrays_as(params, "host")
+    b = [dataclasses.replace(a[0], w_exp=_one_step_off(a[0].w_exp, (0, 0)))
+         ] + a[1:]
+    ex = fastpath.fast_executor(prog, a)
+    assert fastpath.fast_executor(prog, b) is ex
+    refs = [run_program(prog, x_q, p) for p in (a, b)]
+    assert not np.array_equal(*refs)
+    uploads = []
+    for i in (0, 1, 0):
+        np.testing.assert_array_equal(ex(x_q, (a, b)[i]), refs[i])
+        uploads.append(ex.weight_uploads)
+    assert np.diff(uploads).tolist() == [1, 1]    # the one array that differs
+
+
 def test_run_fast_rejects_bad_input_shape():
     specs, params, _ = _chain_fixture()
     prog = compile_network(specs, HW, HW, "fused")

@@ -8,8 +8,9 @@ read back with the benchmark's own reader (``chipbench.reduce.load``):
 * the first call with a new input shape holds a ``fastpath.compile`` span
   in each of its two dispatches and a repeat holds none, and ``n_traces``
   counts the shapes;
-* the spans carry their args (bytes and array counts of the uploads,
-  arrays copied back from the device).
+* the spans carry their args: the first call uploads the stage arrays
+  given as host arrays and every later call none, no call copies a stage
+  array back from the device, and the input's bytes.
 """
 
 import dataclasses
@@ -131,14 +132,21 @@ def test_span_args(traced):
             for e in line.events:
                 if e.name in PHASES[:3]:      # the spans with args
                     stats.setdefault(e.name, []).append(dict(e.stats))
-    named = [(name, getattr(params[st.block], name)) for st in ex.stages
-             for name in fastpath._STAGE_ARRAYS[st.kind]]
+    host = [(name, getattr(params[st.block], name)) for st in ex.stages
+            for name in fastpath._STAGE_ARRAYS[st.kind]
+            if not isinstance(getattr(params[st.block], name), jax.Array)]
     itemsize = {"w": 1, "b": 4, "m": 4}       # int8, int32, float32
-    n_bytes = sum(np.size(v) * itemsize[name[0]] for name, v in named)
-    assert len(named) == 9 * len(CHAIN)
+    n_bytes = sum(np.size(v) * itemsize[name[0]] for name, v in host)
+    n = 9 * len(CHAIN)
+    assert len(host) == 9                     # the second block's
+    # the first call uploads the host arrays, every later call none
     assert stats["fastpath.launch"] == [
-        {"arrays": len(named), "bytes": n_bytes}] * len(CALLS)
-    assert stats["fastpath.weights"] == [{"d2h_arrays": 9}] * len(CALLS)
+        {"arrays": len(host), "bytes": n_bytes}] + [
+        {"arrays": 0, "bytes": 0}] * (len(CALLS) - 1)
+    assert stats["fastpath.weights"] == [
+        {"d2h_arrays": 0, "reused": n - len(host), "uploaded": len(host)}
+    ] + [{"d2h_arrays": 0, "reused": n, "uploaded": 0}] * (len(CALLS) - 1)
+    assert (ex.weight_uploads, ex.weight_binds) == (len(host), 1)
     frame = HW * HW * CHAIN[0].cin
     assert [s["bytes"] for s in stats["fastpath.put_input"]] == [
         (b or 1) * frame for b, _ in CALLS]
