@@ -34,7 +34,8 @@ SET_BASE  bind a base register (IN/OUT/F1/F2) to a (space, address)
 LD_WGT    stream one engine's weights (EXP/DW/PROJ) for a block index
 LD_WIN    gather the 3x3xC input window for an output pixel (OTF padding)
 LD_VEC    load one channel vector of a materialized map   (layer-by-layer)
-LD_TILE   load a 3x3 window of a materialized map         (layer-by-layer)
+LD_TILE   load a 3x3 window of a materialized map (layer-by-layer; the
+          input map itself for a block without expansion)
 EXP_MAC   expansion MACs: window (or vector) x W_exp -> int32 accumulator
 DW_MAC    depthwise MACs: F1 tile x W_dw -> int32 accumulator
 PROJ_MAC  projection MACs: F2 vector x W_proj -> int32 accumulator
@@ -56,6 +57,8 @@ CFG_CORE  latch this stream's pipeline-stage slot (core i of n) — the
           multi-stream segment streams are self-describing
 CFG_DBUF  bind a base register to a double-buffered boundary region
           (ping/pong base pair, resolved by the core's frame parity)
+CFG_X     the channel counts' bits above CFG's fields; follows the CFG it
+          widens, emitted only when a count does not fit (e.g. 1280)
 ======== ====================================================================
 
 Full-network simulation (PR 2)

@@ -52,6 +52,14 @@ Schedule lowering (per ``DSCBlock``):
   config whose folded transform could overflow int32). Stride-2 blocks
   fall back to ``fused`` at scheduling time.
 
+A block without expansion (t=1, ``cmid == cin``) loads no EXP weights and
+emits no ``EXP_MAC``: under ``fused`` its depthwise reads the input window
+(``LD_TILE IN``), under the layer schedules the depthwise pass reads the
+input map and only F2 is materialized. ``fused-rowtile`` and
+``fused-winograd`` have nothing to expand into their strip, so such a
+block falls back to ``fused``; ``meta["rerouted"]`` records every
+fallback as ``{block: requested schedule}``.
+
 Multi-stream compilation (``streams=N``): the op chain is partitioned
 into N contiguous segments, one CFU core per segment, each core owning a
 different pipeline stage of consecutive frames behind the shared DRAM
@@ -214,18 +222,28 @@ def assign_schedules(ir: IRProgram, schedule: ScheduleSpec, *,
         uniform = _resolve_one(schedule)
         for op in ir.dsc_blocks():
             op.schedule, op.tile_rows = uniform, tile_rows
-    _winograd_fallback(ir)
+    ir.extra_meta["rerouted"] = _fallback(ir)
 
 
-def _winograd_fallback(ir: IRProgram) -> None:
-    """F(2x2,3x3) covers stride-1 windows only: a stride-2 block asked to
-    run fused-winograd falls back to the plain fused dataflow (same
-    traffic, direct depthwise). Under ``auto`` the winograd candidate
-    therefore *ties* fused on stride-2 blocks and the enum-order
-    tie-break keeps fused — the fallback never changes an auto pick."""
+def _fallback(ir: IRProgram) -> Dict[str, str]:
+    """Blocks a schedule cannot take run the plain fused dataflow instead;
+    returns ``{block: requested schedule}`` of those rerouted.
+
+    F(2x2,3x3) covers stride-1 windows only, and a block without
+    expansion has no strip to expand for rowtile or winograd: the fused
+    dataflow has the same traffic with a direct depthwise. Under ``auto``
+    such candidates therefore *tie* fused and the enum-order tie-break
+    keeps fused — the fallback never changes an auto pick."""
+    rerouted = {}
     for op in ir.dsc_blocks():
-        if op.schedule is CFUSchedule.FUSED_WINOGRAD and op.spec.stride != 1:
+        s = op.schedule
+        if ((s is CFUSchedule.FUSED_WINOGRAD and op.spec.stride != 1)
+                or (s in (CFUSchedule.FUSED_ROWTILE,
+                          CFUSchedule.FUSED_WINOGRAD)
+                    and not op.spec.has_expansion)):
+            rerouted[op.name] = s.value
             op.schedule = CFUSchedule.FUSED
+    return rerouted
 
 
 def _strip_rows(spec, tile_rows: int) -> int:
@@ -253,8 +271,10 @@ def materialize_scratch(ir: IRProgram) -> None:
         if op.schedule in (CFUSchedule.LAYER_DRAM, CFUSchedule.LAYER_SRAM):
             space = (isa.SPACE_SRAM if op.schedule is CFUSchedule.LAYER_SRAM
                      else isa.SPACE_DRAM)
-            for nm, shape in ((f"f1@{op.name}", (bh, bw, spec.cmid)),
-                              (f"f2@{op.name}", (h2, w2, spec.cmid))):
+            maps = [(f"f1@{op.name}", (bh, bw, spec.cmid)),
+                    (f"f2@{op.name}", (h2, w2, spec.cmid))]
+            # without expansion F1 is the block input: only F2 is made
+            for nm, shape in maps[0 if spec.has_expansion else 1:]:
                 ir.add_value(ir_mod.Value(nm, shape, space=space,
                                           def_idx=oi, last_use=oi,
                                           scratch=True))
@@ -295,6 +315,11 @@ class _InstrSel:
     def emit(self, op: str, *args):
         self.instrs.append(Instr(op, tuple(args)))
 
+    def cfg(self, cin: int, cmid: int, cout: int, stride: int, h: int,
+            w: int):
+        """CFG, and CFG_X where a channel count is wider than its field."""
+        self.instrs.extend(isa.cfg_instrs(cin, cmid, cout, stride, h, w))
+
     def bar(self):
         self.emit("BAR", self.phase % 256)
         self.phase += 1
@@ -320,7 +345,7 @@ class _InstrSel:
         """3x3 stride-2 standard conv (the VWW stem) on the expansion
         array: same halo-aware LD_WIN gather as the depthwise windows."""
         h2, w2 = -(-op.h // op.stride), -(-op.w // op.stride)
-        self.emit("CFG", op.cin, op.cout, op.cout, op.stride, op.h, op.w)
+        self.cfg(op.cin, op.cout, op.cout, op.stride, op.h, op.w)
         self.bind(isa.REG_IN, op.inputs[0])
         self.bind(isa.REG_OUT, op.outputs[0])
         self.emit("LD_WGT", isa.WGT_CONV, op.param_idx)
@@ -334,7 +359,7 @@ class _InstrSel:
 
     def op_head1x1(self, op: Head1x1):
         """1x1 conv + ReLU6 (the classifier head) = EXP_MAC in VEC mode."""
-        self.emit("CFG", op.cin, op.cout, op.cout, 1, op.h, op.w)
+        self.cfg(op.cin, op.cout, op.cout, 1, op.h, op.w)
         self.bind(isa.REG_IN, op.inputs[0])
         self.bind(isa.REG_OUT, op.outputs[0])
         self.emit("LD_WGT", isa.WGT_EXP, op.param_idx)
@@ -349,7 +374,7 @@ class _InstrSel:
     def op_gap_fc(self, gap: GAP, fc: FC):
         """GAP + FC pattern-matched into one unit: the pooled vector lands
         on the projection port (GAP_FIN) and is consumed in place."""
-        self.emit("CFG", gap.ch, gap.ch, fc.cout, 1, gap.h, gap.w)
+        self.cfg(gap.ch, gap.ch, fc.cout, 1, gap.h, gap.w)
         self.bind(isa.REG_IN, gap.inputs[0])
         self.bind(isa.REG_OUT, fc.outputs[0])
         self.emit("LD_WGT", isa.WGT_PROJ, fc.param_idx)
@@ -367,7 +392,7 @@ class _InstrSel:
     def op_dsc_block(self, op: DSCBlock):
         assert op.spec.kernel == isa.KERNEL, "the CFU's depthwise is 3x3"
         spec, bh, bw = op.spec, op.h, op.w
-        self.emit("CFG", spec.cin, spec.cmid, spec.cout, spec.stride, bh, bw)
+        self.cfg(spec.cin, spec.cmid, spec.cout, spec.stride, bh, bw)
         if op.schedule is CFUSchedule.FUSED_ROWTILE:
             self.emit("CFG_STRIP", _strip_rows(spec, op.tile_rows))
         elif op.schedule is CFUSchedule.FUSED_WINOGRAD:
@@ -384,7 +409,8 @@ class _InstrSel:
                            CFUSchedule.FUSED_WINOGRAD):
             self.bind(isa.REG_F1, op.scratch[0])
         for which in (isa.WGT_EXP, isa.WGT_DW, isa.WGT_PROJ):
-            self.emit("LD_WGT", which, op.param_idx)
+            if which != isa.WGT_EXP or spec.has_expansion:
+                self.emit("LD_WGT", which, op.param_idx)
         if op.schedule is CFUSchedule.FUSED:
             self._dsc_fused(op)
         elif op.schedule is CFUSchedule.FUSED_ROWTILE:
@@ -402,9 +428,12 @@ class _InstrSel:
         self.bar()
         for oy in range(h2):
             for ox in range(w2):
-                self.emit("LD_WIN", oy, ox)
-                self.emit("EXP_MAC", isa.MODE_WIN)
-                self.emit("REQUANT", isa.STAGE_F1)
+                if spec.has_expansion:
+                    self.emit("LD_WIN", oy, ox)
+                    self.emit("EXP_MAC", isa.MODE_WIN)
+                    self.emit("REQUANT", isa.STAGE_F1)
+                else:                   # t=1: the window IS the F1 tile
+                    self.emit("LD_TILE", isa.REG_IN, oy, ox)
                 self.emit("DW_MAC")
                 self.emit("REQUANT", isa.STAGE_F2)
                 self.emit("PROJ_MAC")
@@ -414,24 +443,28 @@ class _InstrSel:
                 self.emit("ST_PX", oy, ox)
 
     def _dsc_layer(self, op: DSCBlock):
-        """Layer-by-layer: three passes over planned F1/F2 regions."""
+        """Layer-by-layer: three passes over planned F1/F2 regions (two
+        without expansion: the depthwise pass reads the input map)."""
         spec, bh, bw = op.spec, op.h, op.w
         h2, w2 = spec.out_hw(bh, bw)
-        self.bind(isa.REG_F1, op.scratch[0])
-        self.bind(isa.REG_F2, op.scratch[1])
+        f1_reg = isa.REG_F1 if spec.has_expansion else isa.REG_IN
+        if spec.has_expansion:
+            self.bind(isa.REG_F1, op.scratch[0])
+        self.bind(isa.REG_F2, op.scratch[-1])
         # pass 1: expansion at input resolution, F1 materialized
-        self.bar()
-        for y in range(bh):
-            for x in range(bw):
-                self.emit("LD_VEC", isa.REG_IN, y, x)
-                self.emit("EXP_MAC", isa.MODE_VEC)
-                self.emit("REQUANT", isa.STAGE_F1)
-                self.emit("ST_VEC", isa.REG_F1, y, x)
+        if spec.has_expansion:
+            self.bar()
+            for y in range(bh):
+                for x in range(bw):
+                    self.emit("LD_VEC", isa.REG_IN, y, x)
+                    self.emit("EXP_MAC", isa.MODE_VEC)
+                    self.emit("REQUANT", isa.STAGE_F1)
+                    self.emit("ST_VEC", isa.REG_F1, y, x)
         # pass 2: depthwise over the materialized F1, F2 materialized
         self.bar()
         for oy in range(h2):
             for ox in range(w2):
-                self.emit("LD_TILE", isa.REG_F1, oy, ox)
+                self.emit("LD_TILE", f1_reg, oy, ox)
                 self.emit("DW_MAC")
                 self.emit("REQUANT", isa.STAGE_F2)
                 self.emit("ST_VEC", isa.REG_F2, oy, ox)
@@ -815,6 +848,7 @@ def _compile_ir(ir: IRProgram, schedule: ScheduleSpec,
         m = {
             "schedule": label,
             "block_schedules": block_schedules,
+            "rerouted": dict(ir.extra_meta.get("rerouted", {})),
             "layout": layout,
             "blocks": [(op.name, op.spec, op.h, op.w)
                        for op in ops_seg if isinstance(op, DSCBlock)],

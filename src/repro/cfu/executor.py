@@ -398,6 +398,11 @@ class CFUMachine:
         self.wino_cfg = None     # ... and via CFG_WINO
         self._wino_tiles.clear()
 
+    def _op_cfg_x(self, cin_hi, cmid_hi, cout_hi):
+        # wide-channel extension: the bits above the CFG just latched
+        self.cin, self.cmid, self.cout = isa.widen_cfg(
+            self.cin, self.cmid, self.cout, (cin_hi, cmid_hi, cout_hi))
+
     def _op_cfg_pe(self, exp_pes, dw_lanes, proj_engines):
         pass  # engine counts shape time, never values (timing model only)
 

@@ -21,7 +21,9 @@ has to recognise which network-level stage a CFG unit implements — the
 instruction kinds are unambiguous:
 
 * ``CONV_MAC``                      -> 3x3 stem conv
-* ``DW_MAC``                        -> DSC block (residual iff ``RES_ADD``)
+* ``DW_MAC``                        -> DSC block (residual iff ``RES_ADD``);
+                                       without an EXP weight load, a
+                                       block without expansion (t=1)
 * ``WINO_MAC``                      -> DSC block, winograd depthwise body
 * ``GAP_RST``                       -> GAP + FC classifier unit
 * ``EXP_MAC``-only                  -> head 1x1 conv
@@ -151,7 +153,7 @@ def program_fingerprint(prog) -> str:
 class _Stage:
     """One lifted network-level stage (the unit between CFG words)."""
 
-    kind: str            # "stem" | "dsc" | "head" | "gapfc"
+    kind: str            # "stem" | "dsc" | "dw" (t=1) | "head" | "gapfc"
     block: int           # LD_WGT.block -> params index
     cin: int
     cmid: int
@@ -183,8 +185,10 @@ def _lift_stream(instrs: Sequence[isa.Instr]) -> List[_Stage]:
             raise FastPathError(f"instruction {ins.op} before first CFG")
     stages = []
     for unit in units:
-        cfg = unit[0]
-        cin, cmid, cout, stride, h, w = cfg.args
+        cin, cmid, cout, stride, h, w = unit[0].args
+        for ins in unit:
+            if ins.op == "CFG_X":
+                cin, cmid, cout = isa.widen_cfg(cin, cmid, cout, ins.args)
         ops = {i.op for i in unit}
         wgt = {i.args[0]: i.args[1] for i in unit if i.op == "LD_WGT"}
         residual = "RES_ADD" in ops
@@ -201,13 +205,17 @@ def _lift_stream(instrs: Sequence[isa.Instr]) -> List[_Stage]:
             if strip:                    # rowtile: invert (t-1)*s + k
                 impl, tr = "pallas", max(1, (strip - isa.KERNEL) // stride
                                          + 1)
-            elif "LD_WIN" in ops:        # fused pixel-wise
+            elif "ST_VEC" not in ops:    # fused pixel-wise: nothing stored
                 impl, tr = "pallas", 4
             else:                        # layer-dram / layer-sram
                 impl, tr = "reference", 4
-            stages.append(_Stage("dsc", wgt[isa.WGT_EXP], cin, cmid, cout,
-                                 stride, h, w, residual=residual,
-                                 impl=impl, tile_rows=tr))
+            if isa.WGT_EXP in wgt:
+                kind, block = "dsc", wgt[isa.WGT_EXP]
+            else:                        # t=1: no expansion weights
+                kind, block = "dw", wgt[isa.WGT_DW]
+            stages.append(_Stage(kind, block, cin, cmid, cout, stride, h,
+                                 w, residual=residual, impl=impl,
+                                 tile_rows=tr))
         elif "WINO_MAC" in ops:
             # fused-winograd: no DW_MAC/LD_WIN in the stream, so this must
             # be checked before the EXP_MAC-only head classification
@@ -240,6 +248,7 @@ _STAGE_ARRAYS = {
     "stem": ("w_conv", "b_conv", "m_exp"),
     "dsc": ("w_exp", "w_dw", "w_proj", "b_exp", "b_dw", "b_proj",
             "m_exp", "m_dw", "m_proj"),
+    "dw": ("w_dw", "w_proj", "b_dw", "b_proj", "m_dw", "m_proj"),
     "head": ("w_exp", "b_exp", "m_exp"),
     "gapfc": ("w_proj", "b_proj", "m_proj"),
 }
@@ -259,7 +268,7 @@ def _static_key_of(stage: _Stage, p) -> Tuple:
     if stage.kind == "gapfc":
         return ("gapfc", p.qp_out.zero_point)
     spec = p.spec
-    return ("dsc", spec.cin, spec.cmid, spec.cout, spec.stride,
+    return (stage.kind, spec.cin, spec.cmid, spec.cout, spec.stride,
             p.qp_in.zero_point, p.qp_f1.zero_point, p.qp_f2.zero_point,
             p.qp_out.zero_point, p.q6_f1, p.q6_f2,
             _scale_bits(p.qp_in), _scale_bits(p.qp_out))
@@ -267,13 +276,13 @@ def _static_key_of(stage: _Stage, p) -> Tuple:
 
 def _check_stage_params(stage: _Stage, p):
     """Fail fast (and clearly) when params don't match the lifted stream."""
-    need = {"stem": "w_conv", "dsc": "w_dw", "head": "w_exp",
+    need = {"stem": "w_conv", "dsc": "w_dw", "dw": "w_dw", "head": "w_exp",
             "gapfc": "w_proj"}[stage.kind]
     if getattr(p, need, None) is None:
         raise FastPathError(
             f"params[{stage.block}] ({type(p).__name__}) lacks {need!r} "
             f"for a lifted {stage.kind} stage")
-    if stage.kind == "dsc" and (p.spec.cin, p.spec.cmid, p.spec.cout,
+    if stage.kind in ("dsc", "dw") and (p.spec.cin, p.spec.cmid, p.spec.cout,
                                 p.spec.stride) != (stage.cin, stage.cmid,
                                                    stage.cout,
                                                    stage.stride):
@@ -418,7 +427,8 @@ def _build_stage_fn(stage: _Stage, p, use_pallas: bool):
         # dsc_block_reference, matmuls in f32 where exact) — XLA:CPU
         # vectorizes this across the vmap batch; interpret-mode Pallas
         # cannot.
-        zp_f1 = p.qp_f1.zero_point
+        expand = stage.kind == "dsc"
+        zp_f1 = dsc_mod.f1_zero_point(p)
         zp_f2, zp_out = p.qp_f2.zero_point, p.qp_out.zero_point
         q6_f1, q6_f2 = p.q6_f1, p.q6_f2
         s, residual, p0 = stage.stride, stage.residual, p
@@ -427,10 +437,12 @@ def _build_stage_fn(stage: _Stage, p, use_pallas: bool):
 
         def dsc_jnp_fn(x, w):
             h, wd = x.shape[0], x.shape[1]
-            acc = mm(x.reshape(h * wd, cin), w["w_exp"],
-                     cin).reshape(h, wd, cmid) + w["b_exp"]
-            f1 = quant.requantize(acc, w["m_exp"], zp_f1, relu=True,
-                                  relu6_max_q=q6_f1)
+            f1 = x                       # t=1: F1 is the input
+            if expand:
+                acc = mm(x.reshape(h * wd, cin), w["w_exp"],
+                         cin).reshape(h, wd, cmid) + w["b_exp"]
+                f1 = quant.requantize(acc, w["m_exp"], zp_f1, relu=True,
+                                      relu6_max_q=q6_f1)
             f1p = jnp.pad(f1, ((1, 1), (1, 1), (0, 0)),
                           constant_values=zp_f1)
             h2, w2 = -(-h // s), -(-wd // s)
@@ -473,15 +485,24 @@ def _build_stage_fn(stage: _Stage, p, use_pallas: bool):
             if residual:
                 y = dsc_mod.residual_add_q(y, x, p0)
             return y
-        return dsc_pallas_fn
 
-    p0 = p
+        def dw_pallas_fn(x, w):
+            y = kops.dw_block(
+                x, w["w_dw"].reshape(9, cmid), w["w_proj"], w["b_dw"],
+                w["b_proj"], w["m_dw"], w["m_proj"], stride=stride,
+                zps=(zps[0], zps[2], zps[3]), q6=q6[1],
+                tile_rows=tile_rows)
+            if residual:
+                y = dsc_mod.residual_add_q(y, x, p0)
+            return y
+        return dsc_pallas_fn if stage.kind == "dsc" else dw_pallas_fn
+
+    p0, names = p, _STAGE_ARRAYS[stage.kind]
 
     def dsc_ref_fn(x, w):
         # same stage arithmetic as the layer-by-layer oracle, with the
         # weight tensors swapped for the traced arguments
-        pt = dataclasses.replace(p0, **{k: w[k]
-                                        for k in _STAGE_ARRAYS["dsc"]})
+        pt = dataclasses.replace(p0, **{k: w[k] for k in names})
         return dsc_mod.dsc_block_reference(x, pt)
     return dsc_ref_fn
 

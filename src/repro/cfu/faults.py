@@ -155,6 +155,8 @@ def protect_program(program: isa.Program, params: Optional[Sequence] = None,
             cin, cmid, cout, stride, h, w = ins.args
             h2, w2 = -(-h // stride), -(-w // stride)
             strip_rows = 0
+        elif op == "CFG_X":
+            cin, cmid, cout = isa.widen_cfg(cin, cmid, cout, ins.args)
         elif op == "CFG_STRIP":
             strip_rows = ins.args[0]
         elif op == "SET_BASE":

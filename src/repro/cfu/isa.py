@@ -125,6 +125,21 @@ All are opt-in: an unprotected stream encodes byte-identically to PR 8.
 All three check words meter ``check_bytes`` — a CSR-style counter on the
 existing ``CounterBank`` that the timing walker models identically
 (modeled == executed, as everywhere else).
+
+Wide-channel extension
+----------------------
+``CFG`` packs 54 of the 55 bits beside the parity bit, so its channel
+fields stop at 1023 (``cin``, ``cout``) and 4095 (``cmid``): too narrow
+for MobileNetV2's 1280-channel head. ``CFG_X cin_hi, cmid_hi, cout_hi``
+carries the bits of the three channel counts above CFG's fields. It
+directly follows the CFG it widens and is emitted only when a count does
+not fit (``cfg_instrs``), so every stream whose counts fit encodes to the
+same words as before. Decoders widen the latched counts with
+``widen_cfg``.
+
+Blocks without expansion (t=1, ``cmid == cin``) need no new word: they
+load no EXP weights, and their depthwise reads the input map through
+``LD_TILE IN`` (its padding is the input's zero point).
 """
 
 from __future__ import annotations
@@ -179,6 +194,7 @@ OPCODES: Dict[str, int] = {
     "CHK_WGT": 0x19,
     "CHK_SAVE": 0x1A,
     "CHK_CMP": 0x1B,
+    "CFG_X": 0x1C,
 }
 MNEMONICS = {v: k for k, v in OPCODES.items()}
 
@@ -217,7 +233,12 @@ FIELD_SPECS: Dict[str, List[Tuple[str, int]]] = {
     # activation-region checksums through a 16-entry check-register file
     "CHK_SAVE": [("reg", 2), ("chk", 4)],
     "CHK_CMP": [("reg", 2), ("chk", 4)],
+    # the channel counts' bits above CFG's fields (wide-channel extension)
+    "CFG_X": [("cin_hi", 6), ("cmid_hi", 4), ("cout_hi", 6)],
 }
+
+#: CFG's channel fields, in the order CFG_X extends them
+_CFG_CHANNELS = ("cin", "cmid", "cout")
 
 N_CHK_REGS = 16   # check-register file depth (CHK_SAVE/CHK_CMP.chk is 4 bits)
 
@@ -257,6 +278,34 @@ class Program:
 
     def __len__(self) -> int:
         return len(self.instrs)
+
+
+# --- CFG and its wide-channel extension --------------------------------------
+
+
+def _cfg_bits(name: str) -> int:
+    return dict(FIELD_SPECS["CFG"])[name]
+
+
+def cfg_instrs(cin: int, cmid: int, cout: int, stride: int, h: int,
+               w: int) -> List["Instr"]:
+    """The words that latch a block shape: CFG, followed by CFG_X only when
+    a channel count does not fit its CFG field."""
+    chans = (cin, cmid, cout)
+    bits = [_cfg_bits(n) for n in _CFG_CHANNELS]
+    lo = tuple(c & ((1 << b) - 1) for c, b in zip(chans, bits))
+    hi = tuple(c >> b for c, b in zip(chans, bits))
+    out = [Instr("CFG", lo + (stride, h, w))]
+    if any(hi):
+        out.append(Instr("CFG_X", hi))
+    return out
+
+
+def widen_cfg(cin: int, cmid: int, cout: int,
+              x_args: Sequence[int]) -> Tuple[int, int, int]:
+    """The channel counts a CFG latched, widened by the CFG_X after it."""
+    return tuple(c | (hi << _cfg_bits(n)) for c, hi, n
+                 in zip((cin, cmid, cout), x_args, _CFG_CHANNELS))
 
 
 # --- binary assembler / disassembler ---------------------------------------

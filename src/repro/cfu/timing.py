@@ -498,6 +498,9 @@ class _Walker:
                 self.strip_rows = 0
                 self.wino = None
                 self.wino_seen.clear()
+            elif op == "CFG_X":
+                self.cin, self.cmid, self.cout = isa.widen_cfg(
+                    self.cin, self.cmid, self.cout, ins.args)
             elif op == "CFG_STRIP":
                 self.strip_rows = ins.args[0]
             elif op == "CFG_WINO":

@@ -5,6 +5,11 @@ A MobileNetV2 inverted-residual block is the three-stage sandwich
     Expansion (1x1 conv, C -> M) -> Depthwise (3x3, per-channel, stride s)
                                  -> Projection (1x1 conv, M -> N) [-> +residual]
 
+A block whose expansion factor is 1 (``cmid == cin``, MobileNetV2's first
+bottleneck) has no expansion, as in every public reference of the network
+(torchvision's ``InvertedResidual`` adds the 1x1 only when t != 1): its
+depthwise reads the block input, so F1 is the input, in the input's domain.
+
 This module implements the block in three execution disciplines:
 
 * ``dsc_block_reference``      -- layer-by-layer (the paper's v0 baseline):
@@ -61,6 +66,16 @@ class DSCBlockSpec:
     stride: int = 1
     kernel: int = 3    # depthwise kernel (paper: 3x3)
 
+    def __post_init__(self):
+        if self.cmid < self.cin:
+            raise ValueError(f"cmid={self.cmid} < cin={self.cin}: an "
+                             "inverted residual expands (t >= 1)")
+
+    @property
+    def has_expansion(self) -> bool:
+        """t > 1; a t=1 block's depthwise reads its input directly."""
+        return self.cmid != self.cin
+
     @property
     def has_residual(self) -> bool:
         return self.stride == 1 and self.cin == self.cout
@@ -73,7 +88,8 @@ class DSCBlockSpec:
         """Layer-by-layer MAC counts (the paper's Section II formulas)."""
         h2, w2 = self.out_hw(h, w)
         return {
-            "expansion": h * w * self.cin * self.cmid,
+            "expansion": (h * w * self.cin * self.cmid
+                          if self.has_expansion else 0),
             "depthwise": h2 * w2 * self.kernel * self.kernel * self.cmid,
             "projection": h2 * w2 * self.cmid * self.cout,
         }
@@ -86,6 +102,11 @@ class QuantizedDSCParams:
     Biases are int32 and *include* the zero-point correction term
     (-zp_in * sum_k w) so the MAC loops stream raw int8 activations,
     exactly as the paper's engines do (quant.fold_zero_point_correction).
+
+    A block without expansion (``spec.has_expansion`` false) carries
+    ``w_exp``, ``b_exp`` and ``m_exp`` as ``None``; its F1 is the input, so
+    ``b_dw`` folds ``qp_in``'s zero point and ``qp_f1``/``q6_f1`` are not
+    read.
     """
 
     spec: DSCBlockSpec
@@ -114,17 +135,21 @@ class QuantizedDSCParams:
 
 
 def init_dsc_block_f32(key, spec: DSCBlockSpec) -> Dict[str, jnp.ndarray]:
-    """He-initialized float32 weights for one block (training/calibration)."""
+    """He-initialized float32 weights for one block (training/calibration);
+    ``w_exp``/``b_exp`` are ``None`` for a block without expansion."""
     k1, k2, k3 = jax.random.split(key, 3)
-    w_exp = jax.random.normal(k1, (spec.cin, spec.cmid), jnp.float32)
-    w_exp = w_exp * np.sqrt(2.0 / spec.cin)
+    w_exp = b_exp = None
+    if spec.has_expansion:
+        w_exp = jax.random.normal(k1, (spec.cin, spec.cmid), jnp.float32)
+        w_exp = w_exp * np.sqrt(2.0 / spec.cin)
+        b_exp = jnp.zeros((spec.cmid,))
     w_dw = jax.random.normal(k2, (spec.kernel, spec.kernel, spec.cmid))
     w_dw = w_dw * np.sqrt(2.0 / (spec.kernel * spec.kernel))
     w_proj = jax.random.normal(k3, (spec.cmid, spec.cout), jnp.float32)
     w_proj = w_proj * np.sqrt(2.0 / spec.cmid)
     zeros = jnp.zeros
     return {
-        "w_exp": w_exp, "b_exp": zeros((spec.cmid,)),
+        "w_exp": w_exp, "b_exp": b_exp,
         "w_dw": w_dw, "b_dw": zeros((spec.cmid,)),
         "w_proj": w_proj, "b_proj": zeros((spec.cout,)),
     }
@@ -132,8 +157,10 @@ def init_dsc_block_f32(key, spec: DSCBlockSpec) -> Dict[str, jnp.ndarray]:
 
 def dsc_block_f32(x, p: Dict[str, jnp.ndarray], spec: DSCBlockSpec):
     """Float reference semantics (HWC). Used to calibrate the int8 path."""
-    f1 = jnp.einsum("hwc,cm->hwm", x, p["w_exp"]) + p["b_exp"]
-    f1 = jnp.clip(f1, 0.0, 6.0)  # ReLU6
+    f1 = x
+    if spec.has_expansion:
+        f1 = jnp.einsum("hwc,cm->hwm", x, p["w_exp"]) + p["b_exp"]
+        f1 = jnp.clip(f1, 0.0, 6.0)  # ReLU6
     f1p = jnp.pad(f1, ((1, 1), (1, 1), (0, 0)))
     s, k = spec.stride, spec.kernel
     h2, w2 = spec.out_hw(x.shape[0], x.shape[1])
@@ -164,7 +191,9 @@ def quantize_dsc_block(params_f32: Dict[str, jnp.ndarray],
     p = {k: np.asarray(v) for k, v in params_f32.items()}
     # --- activation ranges from a float forward pass -----------------------
     x = np.asarray(calib_x, np.float32)
-    f1 = np.clip(np.einsum("hwc,cm->hwm", x, p["w_exp"]) + p["b_exp"], 0, 6)
+    expand = spec.has_expansion
+    f1 = (np.clip(np.einsum("hwc,cm->hwm", x, p["w_exp"]) + p["b_exp"],
+                  0, 6) if expand else x)
     f1p = np.pad(f1, ((1, 1), (1, 1), (0, 0)))
     s, k = spec.stride, spec.kernel
     h2, w2 = spec.out_hw(x.shape[0], x.shape[1])
@@ -177,15 +206,14 @@ def quantize_dsc_block(params_f32: Dict[str, jnp.ndarray],
     y = np.einsum("hwm,mn->hwn", f2, p["w_proj"]) + p["b_proj"]
 
     qp_in = quant.choose_qparams(x)
-    qp_f1 = quant.choose_qparams(f1)   # ReLU6 output: range ~[0, 6]
+    # ReLU6 output: range ~[0, 6]; without expansion F1 is the input
+    qp_f1 = quant.choose_qparams(f1) if expand else qp_in
     qp_f2 = quant.choose_qparams(f2)
     qp_out = quant.choose_qparams(y)
 
     # --- weights: per-output-channel symmetric -----------------------------
-    qp_wexp = quant.choose_qparams(p["w_exp"], channel_axis=1)
     qp_wdw = quant.choose_qparams(p["w_dw"], channel_axis=2)
     qp_wproj = quant.choose_qparams(p["w_proj"], channel_axis=1)
-    w_exp_q = np.asarray(quant.quantize(p["w_exp"], qp_wexp, channel_axis=1))
     w_dw_q = np.asarray(quant.quantize(p["w_dw"], qp_wdw, channel_axis=2))
     w_proj_q = np.asarray(quant.quantize(p["w_proj"], qp_wproj, channel_axis=1))
 
@@ -193,14 +221,24 @@ def quantize_dsc_block(params_f32: Dict[str, jnp.ndarray],
     def qbias(b, s_in, s_w):
         return np.round(b / (np.asarray(s_in) * np.asarray(s_w))).astype(np.int64)
 
-    b_exp = (qbias(p["b_exp"], qp_in.scale, qp_wexp.scale)
-             + quant.fold_zero_point_correction(w_exp_q, qp_in.zero_point, (0,)))
+    w_exp_q = b_exp = m_exp = None
+    if expand:
+        qp_wexp = quant.choose_qparams(p["w_exp"], channel_axis=1)
+        w_exp_q = jnp.asarray(
+            quant.quantize(p["w_exp"], qp_wexp, channel_axis=1))
+        b_exp = jnp.asarray(
+            qbias(p["b_exp"], qp_in.scale, qp_wexp.scale)
+            + quant.fold_zero_point_correction(np.asarray(w_exp_q),
+                                               qp_in.zero_point, (0,)),
+            jnp.int32)
+        m_exp = jnp.asarray(quant.effective_scale(qp_in.scale,
+                                                  qp_wexp.scale,
+                                                  qp_f1.scale))
     b_dw = (qbias(p["b_dw"], qp_f1.scale, qp_wdw.scale)
             + quant.fold_zero_point_correction(w_dw_q, qp_f1.zero_point, (0, 1)))
     b_proj = (qbias(p["b_proj"], qp_f2.scale, qp_wproj.scale)
               + quant.fold_zero_point_correction(w_proj_q, qp_f2.zero_point, (0,)))
 
-    m_exp = quant.effective_scale(qp_in.scale, qp_wexp.scale, qp_f1.scale)
     m_dw = quant.effective_scale(qp_f1.scale, qp_wdw.scale, qp_f2.scale)
     m_proj = quant.effective_scale(qp_f2.scale, qp_wproj.scale, qp_out.scale)
 
@@ -209,12 +247,12 @@ def quantize_dsc_block(params_f32: Dict[str, jnp.ndarray],
 
     return QuantizedDSCParams(
         spec=spec,
-        w_exp=jnp.asarray(w_exp_q), w_dw=jnp.asarray(w_dw_q),
+        w_exp=w_exp_q, w_dw=jnp.asarray(w_dw_q),
         w_proj=jnp.asarray(w_proj_q),
-        b_exp=jnp.asarray(b_exp, jnp.int32), b_dw=jnp.asarray(b_dw, jnp.int32),
+        b_exp=b_exp, b_dw=jnp.asarray(b_dw, jnp.int32),
         b_proj=jnp.asarray(b_proj, jnp.int32),
         qp_in=qp_in, qp_f1=qp_f1, qp_f2=qp_f2, qp_out=qp_out,
-        m_exp=jnp.asarray(m_exp), m_dw=jnp.asarray(m_dw),
+        m_exp=m_exp, m_dw=jnp.asarray(m_dw),
         m_proj=jnp.asarray(m_proj),
         q6_f1=q6(qp_f1), q6_f2=q6(qp_f2),
     )
@@ -231,6 +269,22 @@ def _expansion_acc(x_q, p: QuantizedDSCParams):
     acc = jnp.einsum("...c,cm->...m", x_q.astype(jnp.int32),
                      p.w_exp.astype(jnp.int32))
     return acc + p.b_exp
+
+
+def f1_zero_point(p: QuantizedDSCParams) -> int:
+    """Zero point of F1: the expansion's output domain, or the input's for
+    a block without expansion (its depthwise pads with the input's zp)."""
+    return (p.qp_f1.zero_point if p.spec.has_expansion
+            else p.qp_in.zero_point)
+
+
+def _expansion_f1(x_q, p: QuantizedDSCParams):
+    """int8 input pixels -> int8 F1 pixels (the input itself at t=1)."""
+    if not p.spec.has_expansion:
+        return jnp.asarray(x_q, jnp.int8)
+    return quant.requantize(_expansion_acc(x_q, p), p.m_exp,
+                            p.qp_f1.zero_point, relu=True,
+                            relu6_max_q=p.q6_f1)
 
 
 def _depthwise_acc_from_tile(f1_tile, w_dw, b_dw):
@@ -270,13 +324,11 @@ def dsc_block_reference(x_q, p: QuantizedDSCParams):
     "traffic baseline" for benchmarks.
     """
     spec = p.spec
-    # Stage 1: Expansion over the entire map.
-    f1_q = quant.requantize(_expansion_acc(x_q, p), p.m_exp,
-                            p.qp_f1.zero_point, relu=True,
-                            relu6_max_q=p.q6_f1)
+    # Stage 1: Expansion over the entire map (F1 is the input at t=1).
+    f1_q = _expansion_f1(x_q, p)
     # Explicit padded intermediate (what the fused dataflow eliminates).
     f1_pad = jnp.pad(f1_q, ((1, 1), (1, 1), (0, 0)),
-                     constant_values=p.qp_f1.zero_point)
+                     constant_values=f1_zero_point(p))
     s, k = spec.stride, spec.kernel
     h2, w2 = spec.out_hw(x_q.shape[0], x_q.shape[1])
     acc = jnp.zeros((h2, w2, spec.cmid), jnp.int32)
@@ -343,9 +395,7 @@ def dsc_block_fused_pixelwise(x_q, p: QuantizedDSCParams):
         wy, wx = flat_iy[idx], flat_ix[idx]
         # --- Expansion stage: 3x3xC window -> 3x3xM F1 tile (registers) ----
         win = gather_window_otf(x_q, wy, wx, p.qp_in.zero_point)
-        f1_tile = quant.requantize(_expansion_acc(win, p), p.m_exp,
-                                   p.qp_f1.zero_point, relu=True,
-                                   relu6_max_q=p.q6_f1)
+        f1_tile = _expansion_f1(win, p)
         # The *expansion*'s own input window needs on-the-fly padding too:
         # positions whose source pixel was padding must yield F1 = zp_f1
         # after the depthwise sees them. Since expansion(zp_in-pad pixel)
@@ -354,7 +404,7 @@ def dsc_block_fused_pixelwise(x_q, p: QuantizedDSCParams):
         h, w = x_q.shape[0], x_q.shape[1]
         valid = (wy >= 0) & (wy < h) & (wx >= 0) & (wx < w)
         f1_tile = jnp.where(valid[..., None], f1_tile,
-                            jnp.asarray(p.qp_f1.zero_point, jnp.int8))
+                            jnp.asarray(f1_zero_point(p), jnp.int8))
         # --- Depthwise stage: 3x3xM tile -> M-vector F2 (registers) --------
         acc = _depthwise_acc_from_tile(f1_tile, p.w_dw, p.b_dw)
         f2_vec = quant.requantize(acc, p.m_dw, p.qp_f2.zero_point,
@@ -405,11 +455,9 @@ def dsc_block_fused_rowtile(x_q, p: QuantizedDSCParams, tile_rows: int = 4):
         strip = x_q[jnp.clip(rows, 0, h - 1)[:, None],
                     jnp.clip(cols, 0, w - 1)[None, :]]
         valid = valid_r[:, None] & valid_c[None, :]
-        f1 = quant.requantize(_expansion_acc(strip, p), p.m_exp,
-                              p.qp_f1.zero_point, relu=True,
-                              relu6_max_q=p.q6_f1)
+        f1 = _expansion_f1(strip, p)
         f1 = jnp.where(valid[..., None], f1,
-                       jnp.asarray(p.qp_f1.zero_point, jnp.int8))
+                       jnp.asarray(f1_zero_point(p), jnp.int8))
         # --- Depthwise over the strip (VMEM-resident, never stored) --------
         acc = jnp.zeros((tile_rows, w2, spec.cmid), jnp.int32)
         for dy in range(k):

@@ -37,6 +37,7 @@ import jax.numpy as jnp
 from repro.core import dsc as dsc_mod
 from repro.core import quant
 from repro.core.dsc import DSCBlockSpec, QuantizedDSCParams
+from repro.core.traffic import intermediate_feature_bytes
 
 
 class Schedule(enum.Enum):
@@ -84,12 +85,10 @@ def dsc_block_pipelined(x_q, p: QuantizedDSCParams):
         wy = flat_iy[jnp.clip(idx, 0, n - 1)]
         wx = flat_ix[jnp.clip(idx, 0, n - 1)]
         win = dsc_mod.gather_window_otf(x_q, wy, wx, p.qp_in.zero_point)
-        f1 = quant.requantize(dsc_mod._expansion_acc(win, p), p.m_exp,
-                              p.qp_f1.zero_point, relu=True,
-                              relu6_max_q=p.q6_f1)
+        f1 = dsc_mod._expansion_f1(win, p)
         valid = (wy >= 0) & (wy < h) & (wx >= 0) & (wx < w)
         return jnp.where(valid[..., None], f1,
-                         jnp.asarray(p.qp_f1.zero_point, jnp.int8))
+                         jnp.asarray(dsc_mod.f1_zero_point(p), jnp.int8))
 
     def stage_dw(f1_tile):
         acc = dsc_mod._depthwise_acc_from_tile(f1_tile, p.w_dw, p.b_dw)
@@ -108,7 +107,7 @@ def dsc_block_pipelined(x_q, p: QuantizedDSCParams):
         return (f1_next, f2_next), y
 
     f1_0 = jnp.full((spec.kernel, spec.kernel, spec.cmid),
-                    p.qp_f1.zero_point, jnp.int8)
+                    dsc_mod.f1_zero_point(p), jnp.int8)
     f2_0 = jnp.full((spec.cmid,), p.qp_f2.zero_point, jnp.int8)
     # n + 2 ticks: 2 fill ticks produce garbage outputs that we drop.
     _, ys = jax.lax.scan(tick, (f1_0, f2_0), jnp.arange(n + 2))
@@ -168,12 +167,14 @@ class CycleReport:
 
 
 def _stage_cycles_per_pixel(spec: DSCBlockSpec) -> Dict[str, float]:
-    """Effective (calibrated) per-pixel latency of each pipeline stage."""
+    """Effective (calibrated) per-pixel latency of each pipeline stage (no
+    expansion stage for a block without expansion)."""
     m, c, n = spec.cmid, spec.cin, spec.cout
     groups = -(-n // PROJECTION_ENGINES)
+    ex = spec.has_expansion
     return {
-        "ex_mac": C_EX_PER_IN_CH * c * m,
-        "ex_q": C_EXQ * m,
+        "ex_mac": C_EX_PER_IN_CH * c * m * ex,
+        "ex_q": C_EXQ * m * ex,
         "dw_mac": C_DW * m,
         "dw_q": C_DWQ * m,
         "pr_mac": C_PR * m * groups,
@@ -185,7 +186,7 @@ def nominal_stage_cycles_per_pixel(spec: DSCBlockSpec) -> Dict[str, float]:
     m, c, n = spec.cmid, spec.cin, spec.cout
     k2 = spec.kernel * spec.kernel
     return {
-        "ex_mac": k2 * m * c / EXPANSION_MACS_PER_CYCLE,
+        "ex_mac": k2 * m * c * spec.has_expansion / EXPANSION_MACS_PER_CYCLE,
         "dw_mac": k2 * m / DEPTHWISE_MACS_PER_CYCLE,
         "pr_mac": m * -(-n // PROJECTION_ENGINES),
     }
@@ -204,7 +205,7 @@ def modeled_cycles(spec: DSCBlockSpec, h: int, w: int,
         mac_cycles = sum(
             m * (SW_CYCLES_PER_MAC_A + SW_CYCLES_PER_LOOP_B / inner[k])
             for k, m in macs.items())
-        xfer_bytes = 2 * (h * w * spec.cmid) + 2 * (h2 * w2 * spec.cmid)
+        xfer_bytes = intermediate_feature_bytes(spec, h, w)
         return mac_cycles + xfer_bytes * SW_CYCLES_PER_XFER_BYTE
     if schedule is Schedule.V1_PIXEL_SEQUENTIAL:
         return n_px * (sum(st.values()) + C_PX_FIXED)

@@ -38,19 +38,25 @@ def intermediate_feature_bytes(spec: DSCBlockSpec, h: int, w: int) -> int:
     """Paper Eq. 1 (bytes for int8): 2*(H1 W1 C1) + 2*(H2 W2 C2).
 
     F1 is the expanded map (H x W x M, at the *input* resolution), F2 is the
-    depthwise output (H2 x W2 x M).
+    depthwise output (H2 x W2 x M). A block without expansion has no F1 to
+    move: its depthwise reads the block input.
     """
     h2, w2 = spec.out_hw(h, w)
-    return 2 * (h * w * spec.cmid) + 2 * (h2 * w2 * spec.cmid)
+    f1 = 2 * (h * w * spec.cmid) if spec.has_expansion else 0
+    return f1 + 2 * (h2 * w2 * spec.cmid)
 
 
 def min_sram_buffer_bytes(spec: DSCBlockSpec, h: int, w: int) -> int:
-    """Paper Eq. 2: a pipelined non-fused design must buffer all of F1."""
-    return h * w * spec.cmid
+    """Paper Eq. 2: a pipelined non-fused design must buffer all of F1 (all
+    of F2 for a block without expansion, whose F1 is its input)."""
+    if spec.has_expansion:
+        return h * w * spec.cmid
+    h2, w2 = spec.out_hw(h, w)
+    return h2 * w2 * spec.cmid
 
 
 def weight_bytes(spec: DSCBlockSpec) -> int:
-    return (spec.cin * spec.cmid
+    return (spec.cin * spec.cmid * spec.has_expansion
             + spec.kernel * spec.kernel * spec.cmid
             + spec.cmid * spec.cout)
 

@@ -40,6 +40,11 @@ paper's v2/v3 inter/intra-stage pipelining.
 Weight layout: w_dw is passed as (9, M) — tap-major, exactly the paper's
 nine-bank depthwise filter buffer (Fig. 12: bank i holds tap i of every
 filter, so one "row" feeds all MACs of tap i in one go).
+
+A block without expansion (t=1, MobileNetV2's first bottleneck) runs the
+same body with step 2 left out (``dw_pallas``, a static flag): the F1
+strip is filled from the input rows themselves, and the halo carries the
+input's zero point.
 """
 
 from __future__ import annotations
@@ -73,15 +78,17 @@ def _matmul_i32(a_i8, w_i8):
 
 
 def _fused_dsc_kernel(
-    x_ref, w_exp_ref, w_dw_ref, w_proj_ref,
-    b_exp_ref, b_dw_ref, b_proj_ref,
-    m_exp_ref, m_dw_ref, m_proj_ref,
-    out_ref, f1_ref,
-    *, h: int, w: int,
+    *refs, h: int, w: int,
     stride: int, tile_rows: int,
     zp_f1: int, zp_f2: int, zp_out: int,
-    q6_f1: int, q6_f2: int,
+    q6_f1: int, q6_f2: int, expand: bool = True,
 ):
+    if expand:
+        (x_ref, w_exp_ref, w_dw_ref, w_proj_ref, b_exp_ref, b_dw_ref,
+         b_proj_ref, m_exp_ref, m_dw_ref, m_proj_ref, out_ref, f1_ref) = refs
+    else:
+        (x_ref, w_dw_ref, w_proj_ref, b_dw_ref, b_proj_ref, m_dw_ref,
+         m_proj_ref, out_ref, f1_ref) = refs
     t = pl.program_id(0)
     s, k = stride, 3
     w2 = -(-w // s)
@@ -94,11 +101,23 @@ def _fused_dsc_kernel(
     f1_ref[:, :, w + 1:w + 2, :] = zcol
 
     # ---- Expansion stage: MXU int8 matmul + requant + ReLU6, per row -------
-    w_exp = w_exp_ref[...]
-    b_exp, m_exp = b_exp_ref[...], m_exp_ref[...]
+    if expand:
+        w_exp = w_exp_ref[...]
+        b_exp, m_exp = b_exp_ref[...], m_exp_ref[...]
     for i in range(in_rows):           # unrolled: in_rows is small & static
         r = r0 + i
         row = x_ref[jnp.clip(r, 0, h - 1)]                       # (W, C)
+        if not expand:
+            # t=1: F1 is the input row itself, in the input's domain. Its
+            # lanes past C are left as they are: their depthwise weights
+            # are the zero padding, so they add nothing.
+            f1 = jnp.where(jnp.logical_and(r >= 0, r < h),
+                           row.astype(jnp.int32), zp_f1)
+            for c in range(n_chunks):
+                width = min(LANES, f1.shape[1] - c * LANES)
+                f1_ref[c, i, 1:w + 1, 0:width] = \
+                    f1[:, c * LANES:c * LANES + width]
+            continue
         f1 = _requant(_matmul_i32(row, w_exp) + b_exp, m_exp,
                       zp_f1, zp_f1, q6_f1)
         f1 = jnp.where(jnp.logical_and(r >= 0, r < h),
@@ -147,8 +166,37 @@ def fused_dsc_pallas(
       tile_rows: output rows computed per grid step (VMEM working-set knob).
     Returns: (H2, W2, N) int8.
     """
+    return _launch(x_q, (w_exp, b_exp, m_exp), w_dw9, w_proj, b_dw, b_proj,
+                   m_dw, m_proj, stride=stride, zp_f1=zps[1], zp_f2=zps[2],
+                   zp_out=zps[3], q6=q6, tile_rows=tile_rows,
+                   interpret=interpret)
+
+
+def dw_pallas(
+    x_q, w_dw9, w_proj, b_dw, b_proj, m_dw, m_proj,
+    *, stride: int, zps: Tuple[int, int, int], q6: int,
+    tile_rows: int = 4, interpret: bool = False,
+):
+    """Launch the kernel for a block without expansion (t=1, C == M).
+
+    The same body with the expansion left out: F1 is the input map, and
+    out-of-map rows and columns are filled with the input's zero point.
+    ``zps`` is (zp_in, zp_f2, zp_out) and ``q6`` the F2 ReLU6 cap; the
+    other arguments are as for :func:`fused_dsc_pallas`.
+    """
+    return _launch(x_q, None, w_dw9, w_proj, b_dw, b_proj, m_dw, m_proj,
+                   stride=stride, zp_f1=zps[0], zp_f2=zps[1], zp_out=zps[2],
+                   q6=(INT8_MAX, q6), tile_rows=tile_rows,
+                   interpret=interpret)
+
+
+def _launch(x_q, exp, w_dw9, w_proj, b_dw, b_proj, m_dw, m_proj, *,
+            stride: int, zp_f1: int, zp_f2: int, zp_out: int,
+            q6: Tuple[int, int], tile_rows: int, interpret: bool):
+    """One ``pallas_call`` of the block; ``exp`` is (w_exp, b_exp, m_exp),
+    or None for a block whose F1 is its input."""
     h, w, cin = x_q.shape
-    cmid = w_exp.shape[1]
+    cmid = w_dw9.shape[1]
     cout = w_proj.shape[1]
     h2, w2 = -(-h // stride), -(-w // stride)
     # Keep the requested tile granularity even when it doesn't divide h2:
@@ -169,38 +217,49 @@ def fused_dsc_pallas(
     # the block's output is unchanged.
     n_chunks = -(-cmid // LANES)
     pad = n_chunks * LANES - cmid
-    w_exp = jnp.pad(w_exp, ((0, 0), (0, pad)))
+    expand = exp is not None
+    w_exp, b_exp, m_exp = exp if expand else (None, None, None)
+    if expand:
+        w_exp = jnp.pad(w_exp, ((0, 0), (0, pad)))
     w_dw9 = jnp.pad(w_dw9, ((0, 0), (0, pad)))
     w_proj = jnp.pad(w_proj, ((0, pad), (0, 0)))
     # per-channel vectors as (1, n) rows, broadcast over a (W, n) row
-    vecs = [jnp.pad(v, (0, pad)).reshape(1, -1)
-            for v in (b_exp, b_dw, m_exp, m_dw)]
-    b_exp, b_dw, m_exp, m_dw = vecs
+    if expand:
+        vecs = [jnp.pad(v, (0, pad)).reshape(1, -1)
+                for v in (b_exp, b_dw, m_exp, m_dw)]
+        b_exp, b_dw, m_exp, m_dw = vecs
+    else:
+        b_dw, m_dw = [jnp.pad(v, (0, pad)).reshape(1, -1)
+                      for v in (b_dw, m_dw)]
     b_proj, m_proj = b_proj.reshape(1, -1), m_proj.reshape(1, -1)
     cmid_p = n_chunks * LANES
 
     kernel = functools.partial(
         _fused_dsc_kernel, h=h, w=w, stride=stride, tile_rows=tile_rows,
-        zp_f1=zps[1], zp_f2=zps[2], zp_out=zps[3],
-        q6_f1=q6[0], q6_f2=q6[1])
+        zp_f1=zp_f1, zp_f2=zp_f2, zp_out=zp_out,
+        q6_f1=q6[0], q6_f2=q6[1], expand=expand)
 
     whole = lambda shape: pl.BlockSpec(shape, lambda t: (0,) * len(shape))
+    operands = [
+        (x_q, whole((h, w, cin))),        # x: whole map stays in VMEM
+        (w_exp, whole((cin, cmid_p))),    # w_exp (broadcast, like Fig. 11)
+        (w_dw9, whole((9, cmid_p))),      # w_dw nine-bank layout (Fig. 12)
+        (w_proj, whole((cmid_p, cout))),  # w_proj (per-engine LUTRAM, Fig. 8)
+        (b_exp, whole((1, cmid_p))), (b_dw, whole((1, cmid_p))),
+        (b_proj, whole((1, cout))),
+        (m_exp, whole((1, cmid_p))), (m_dw, whole((1, cmid_p))),
+        (m_proj, whole((1, cout))),
+    ]
+    operands = [(a, spec) for a, spec in operands if a is not None]
     y = pl.pallas_call(
         kernel,
         grid=(n_tiles,),
-        in_specs=[
-            whole((h, w, cin)),          # x: whole map stays in VMEM
-            whole((cin, cmid_p)),        # w_exp (broadcast, like Fig. 11)
-            whole((9, cmid_p)),          # w_dw nine-bank layout (Fig. 12)
-            whole((cmid_p, cout)),       # w_proj (per-engine LUTRAM, Fig. 8)
-            whole((1, cmid_p)), whole((1, cmid_p)), whole((1, cout)),
-            whole((1, cmid_p)), whole((1, cmid_p)), whole((1, cout)),
-        ],
+        in_specs=[spec for _, spec in operands],
         out_specs=pl.BlockSpec((tile_rows, w2, cout), lambda t: (t, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((h2p, w2, cout), jnp.int8),
         # the haloed F1 strip of one tile (int32: the depthwise operand)
         scratch_shapes=[pltpu.VMEM((n_chunks, in_rows, w + 2, LANES),
                                    jnp.int32)],
         interpret=interpret,
-    )(x_q, w_exp, w_dw9, w_proj, b_exp, b_dw, b_proj, m_exp, m_dw, m_proj)
+    )(*[a for a, _ in operands])
     return y if h2p == h2 else y[:h2]

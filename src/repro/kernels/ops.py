@@ -41,6 +41,22 @@ def dsc_block(x_q, w_exp, w_dw9, w_proj, b_exp, b_dw, b_proj,
         stride=stride, zps=zps, q6=q6, tile_rows=tile_rows, interpret=interp)
 
 
+@functools.partial(jax.jit, static_argnames=("stride", "zps", "q6",
+                                             "tile_rows", "interpret"))
+def dw_block(x_q, w_dw9, w_proj, b_dw, b_proj, m_dw, m_proj, *,
+             stride: int, zps, q6: int, tile_rows: int = 4,
+             interpret: Optional[bool] = None):
+    """One fused Dw->Pr block without expansion (t=1; no residual add).
+
+    Its own entry, so the device trace names its kernel ``jit_dw_block``
+    apart from the expanding blocks' ``jit_dsc_block``. ``zps`` is
+    (zp_in, zp_f2, zp_out), ``q6`` the F2 ReLU6 cap."""
+    interp = default_interpret() if interpret is None else interpret
+    return _dsc.dw_pallas(
+        x_q, w_dw9, w_proj, b_dw, b_proj, m_dw, m_proj, stride=stride,
+        zps=zps, q6=q6, tile_rows=tile_rows, interpret=interp)
+
+
 # --- fused FFN -------------------------------------------------------------
 
 

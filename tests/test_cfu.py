@@ -52,6 +52,9 @@ SPECS = [
     (DSCBlockSpec(cin=5, cmid=30, cout=7, stride=1), 9),     # odd dims
     (DSCBlockSpec(cin=4, cmid=24, cout=4, stride=2), 7),     # odd hw, s2
     (DSCBlockSpec(cin=6, cmid=18, cout=6, stride=1), 6),     # residual, tiny
+    # t=1, no expansion: no EXP weights or MACs, the depthwise reads IN
+    (DSCBlockSpec(cin=6, cmid=6, cout=6, stride=1), 7),      # residual
+    (DSCBlockSpec(cin=5, cmid=5, cout=9, stride=2), 9),      # odd hw, s2
 ]
 
 
@@ -603,6 +606,78 @@ def test_every_opcode_roundtrips_through_binary_and_text():
             assert isa.asm_to_instr(isa.instr_to_asm(ins)) == ins
 
 
+@pytest.mark.parametrize("chans", [(320, 1280, 1280),     # the 1x1 head
+                                   (1280, 1280, 1000),    # GAP + FC
+                                   (4095, 4095, 1023)])   # widest in CFG
+def test_cfg_x_carries_channel_counts_wider_than_cfg(chans):
+    """A channel count past its CFG field rides in a CFG_X word after the
+    CFG; it encodes and decodes round trip, under word parity too. A
+    shape that fits emits CFG alone."""
+    words = isa.cfg_instrs(*chans, 1, 7, 7)
+    assert [w.op for w in words] == (["CFG", "CFG_X"] if max(
+        chans[0], chans[2]) > 1023 else ["CFG"])
+    prog = isa.Program(words + [isa.Instr("HALT")], meta={"parity": True})
+    enc = isa.encode_program(prog)
+    assert isa.bad_parity_indices(enc) == []
+    dec = isa.decode_words(enc)
+    assert dec == prog.instrs
+    got = dec[0].args[:3]
+    if len(words) == 2:
+        got = isa.widen_cfg(*got, dec[1].args)
+        flipped = enc.copy()
+        flipped[1] ^= np.uint64(1 << 40)      # one bit of the CFG_X word
+        assert isa.bad_parity_indices(flipped) == [1]
+    assert tuple(got) == chans
+
+
+def test_vww_program_words_unchanged_by_the_wide_cfg():
+    """Programs whose counts fit CFG encode to the same words as before
+    CFG_X existed (sha256 of the 80x80 VWW streams, every schedule, and
+    the parity-protected fused stream, taken before the change)."""
+    import hashlib
+    from repro.cfu.compiler import schedule_names
+    h = hashlib.sha256()
+    for s in schedule_names():
+        h.update(isa.encode_program(
+            compile_vww_network(block_specs(), 80, s)).tobytes())
+    assert h.hexdigest() == ("925ba400e660d5dda8966f4a632c75f7"
+                             "1a19a361abf5db399261205f84545fb2")
+    prot = isa.encode_program(compile_vww_network(block_specs(), 80,
+                                                  "fused", protect=True))
+    assert hashlib.sha256(prot.tobytes()).hexdigest() == (
+        "269792d617f0e8dbcb44d9360ff794329b77c6cb91e427e4a4447ead90ed8123")
+
+
+def test_wide_head_network_bit_exact_vs_forward_int8():
+    """A 1280-channel head and a 1000-class FC (CFG_X on both units)
+    through the golden executor, the cost model and the fast path, held
+    to the scalar-core reference logits."""
+    from repro.cfu import fastpath
+    from repro.models import mobilenetv2 as mnv2
+    img_hw = 16
+    net = mnv2.init_and_quantize(jax.random.PRNGKey(5), img_hw=img_hw,
+                                 head_ch=1280, n_classes=1000)
+    params = vww_cfu_params(net)
+    rng = np.random.default_rng(5)
+    imgs = rng.standard_normal((2, img_hw, img_hw, 3)).astype(np.float32)
+    imgs_q = np.asarray(quant.quantize(imgs, net.qp_img))
+    ref = np.asarray(mnv2.forward_batch(imgs, net, return_quantized=True))
+    prog = compile_vww_network(block_specs(), img_hw, CFUSchedule.FUSED,
+                               head_ch=1280, n_classes=1000, protect=True)
+    assert sum(i.op == "CFG_X" for i in prog.instrs) == 2
+    np.testing.assert_array_equal(run_program(prog, imgs_q, params), ref)
+    np.testing.assert_array_equal(fastpath.run_fast(prog, imgs_q, params),
+                                  ref)
+    # the cost model sees the widened counts: against the 128-channel,
+    # 2-class network only the head (1x1 map here) and the FC grow
+    wide = analyze(prog).macs_by_engine
+    narrow = analyze(compile_vww_network(
+        block_specs(), img_hw, CFUSchedule.FUSED)).macs_by_engine
+    c_last = block_specs()[-1][1].cout
+    assert wide["exp"] - narrow["exp"] == c_last * (1280 - 128)
+    assert wide["proj"] - narrow["proj"] == 1280 * 1000 - 128 * 2
+
+
 def test_compiled_program_roundtrips():
     spec, hw = DSCBlockSpec(cin=8, cmid=48, cout=16, stride=2), 10
     for sched in CFUSchedule:
@@ -667,6 +742,32 @@ def test_traffic_matches_analytic_for_all_mobilenet_blocks(bi):
     # the paper's Eq. 2 buffer.
     assert rep_f.sram_buffer_bytes == 0
     assert rep_s.sram_buffer_bytes >= min_sram_buffer_bytes(spec, hw, hw)
+
+
+@pytest.mark.parametrize("spec,hw", [
+    (DSCBlockSpec(cin=32, cmid=32, cout=16, stride=1), 28),   # MNV2 first
+    (DSCBlockSpec(cin=8, cmid=8, cout=8, stride=2), 9),
+])
+def test_traffic_matches_analytic_without_expansion(spec, hw):
+    """t=1: the streams load no EXP weights and count no expansion MACs,
+    and their bytes equal Eq. 1/2 with no F1 (only F2 is materialized)."""
+    t = block_traffic(spec, hw, hw)
+    h2, w2 = spec.out_hw(hw, hw)
+    assert t.intermediate_bytes == 2 * h2 * w2 * spec.cmid
+    reps = {s: analyze(compile_block(spec, hw, hw, s))
+            for s in (CFUSchedule.LAYER_DRAM, CFUSchedule.LAYER_SRAM,
+                      CFUSchedule.FUSED)}
+    for rep in reps.values():
+        assert "exp" not in rep.macs_by_engine
+        assert "EXP_MAC" not in rep.retired
+        assert rep.weight_bytes == 9 * spec.cmid + spec.cmid * spec.cout
+    assert reps[CFUSchedule.LAYER_DRAM].dram_bytes == t.baseline_total
+    assert reps[CFUSchedule.LAYER_SRAM].dram_bytes == \
+        t.baseline_total - t.intermediate_bytes
+    assert reps[CFUSchedule.LAYER_SRAM].sram_bytes == t.intermediate_bytes
+    assert reps[CFUSchedule.LAYER_SRAM].sram_buffer_bytes == \
+        min_sram_buffer_bytes(spec, hw, hw)
+    assert reps[CFUSchedule.FUSED].dram_bytes == t.fused_total
 
 
 def test_cycles_match_calibrated_fusion_model():

@@ -110,6 +110,49 @@ def test_matrix_vww_network_fast_equals_interpreter():
                                       err_msg=f"vww single frame")
 
 
+# --- blocks without expansion (t=1) ------------------------------------------
+
+T1_CHAIN = (DSCBlockSpec(cin=5, cmid=5, cout=5, stride=1),    # residual
+            DSCBlockSpec(cin=5, cmid=15, cout=6, stride=2),
+            DSCBlockSpec(cin=6, cmid=6, cout=4, stride=2))    # odd map
+
+
+@functools.lru_cache(maxsize=None)
+def _t1_fixture():
+    from repro.cfu.network import random_chain_params
+    specs = [(f"t{i}", s) for i, s in enumerate(T1_CHAIN)]
+    params = random_chain_params(jax.random.PRNGKey(4), specs, HW, seed=4)
+    rng = np.random.default_rng(4)
+    x_q = rng.integers(-128, 128, (3, HW, HW, 5), dtype=np.int8)
+    want = x_q
+    for p in params:
+        want = np.asarray(jax.vmap(
+            functools.partial(dsc.dsc_block_reference, p=p))(want))
+    return specs, params, x_q, want
+
+
+@pytest.mark.parametrize("sched", schedule_names(include_auto=True))
+def test_t1_chain_every_path_equals_reference(sched):
+    """A chain with blocks without expansion: the compiler's stream on the
+    golden executor and the fast path (and, under fused, the Pallas
+    bodies) all equal the layer-by-layer reference; strip schedules send
+    those blocks to fused and say so in the meta."""
+    specs, params, x_q, want = _t1_fixture()
+    prog = compile_network(specs, HW, HW, sched)
+    np.testing.assert_array_equal(run_program(prog, x_q, params), want)
+    np.testing.assert_array_equal(fastpath.run_fast(prog, x_q, params),
+                                  want)
+    kinds = [st.kind for st in fastpath.fast_executor(prog, params).stages]
+    assert kinds == ["dw", "dsc", "dw"]
+    if sched in ("fused-rowtile", "fused-winograd"):
+        assert set(prog.meta["rerouted"]) >= {"t0", "t2"}
+        assert all(prog.meta["block_schedules"][n] == "fused"
+                   for n in ("t0", "t2"))
+    if sched == "fused":
+        got = fastpath.run_fast(prog, x_q, params, use_pallas=True)
+        np.testing.assert_array_equal(got, want)
+
+
 # --- fingerprints + cache ---------------------------------------------------
 
 

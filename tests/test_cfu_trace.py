@@ -327,6 +327,45 @@ def test_cfu_cli_trace(tmp_path):
     assert model > 0 and execd   # both lanes landed in one file
 
 
+def test_block_without_expansion_has_no_expansion_phase():
+    """Two blocks under layer-dram, the first without expansion (t=1):
+    the modelled timeline holds one expansion pass (the second block's),
+    the first block adds nothing to the exp MAC counter, the executed
+    counters equal the modelled ones, and the DRAM bytes equal Eq. 1
+    with no F1 for the first block."""
+    from repro.cfu.network import random_chain_params
+    from repro.core.traffic import network_traffic
+    import jax
+    chain = [("a", DSCBlockSpec(cin=8, cmid=8, cout=8, stride=1)),
+             ("b", DSCBlockSpec(cin=8, cmid=48, cout=16, stride=2))]
+    hw = 10
+    prog = compile_network(chain, hw, hw, CFUSchedule.LAYER_DRAM)
+    model = BatchCostModel(prog, "v3")
+    passes = [sorted(p.bound_stage_cycles) for p in model.phases
+              if p.n_iters]
+    assert passes == [["dw_mac", "dw_q"], ["pr_mac"],        # a: 2 passes
+                      ["ex_mac", "ex_q"], ["dw_mac", "dw_q"], ["pr_mac"]]
+    rep = model.report(1)
+    alone = {n: analyze(compile_block(s, hw, hw, CFUSchedule.LAYER_DRAM))
+             for n, s in chain}
+    assert "exp" not in alone["a"].macs_by_engine
+    assert rep.macs_by_engine["exp"] == alone["b"].macs_by_engine["exp"]
+    assert rep.retired["EXP_MAC"] == hw * hw             # b's pixels only
+    params = random_chain_params(jax.random.PRNGKey(2), chain, hw)
+    x_q = np.random.default_rng(2).integers(-128, 128, (2, hw, hw, 8),
+                                            dtype=np.int8)
+    _, stats = run_program(prog, x_q, params, return_stats=True)
+    assert _nonclock_diff(model.report(2).counter_bank(),
+                          stats.counter_bank()) == {}
+    tr = Tracer()
+    model.emit_trace(tr, 1)
+    c = tr.last_counter("model.bytes", pid=0)
+    want = network_traffic([(n, s, hw, hw) for n, s in chain])
+    assert int(c["dram_rd"] + c["dram_wr"]) == rep.dram_bytes == \
+        want["baseline_total"]
+    assert want["rows"][0].intermediate_bytes == 2 * hw * hw * 8   # F2 only
+
+
 # --- hypothesis property -----------------------------------------------------
 
 
@@ -341,9 +380,10 @@ if HAVE_HYPOTHESIS:
         sched = data.draw(st.sampled_from(ALL_SCHEDULES))
         streams = data.draw(st.integers(1, 2))
         batch = data.draw(st.integers(1, 3))
-        spec = DSCBlockSpec(
-            cin=data.draw(st.integers(2, 8)),
-            cmid=data.draw(st.integers(6, 24)),
+        cin = data.draw(st.integers(2, 8))
+        spec = DSCBlockSpec(   # an inverted residual: cmid >= cin
+            cin=cin,
+            cmid=data.draw(st.integers(max(6, cin), 24)),
             cout=data.draw(st.integers(2, 8)),
             stride=data.draw(st.sampled_from([1, 2])))
         hw = data.draw(st.sampled_from([6, 8, 10]))
@@ -358,11 +398,8 @@ if HAVE_HYPOTHESIS:
             assert tr.span_cycles(pid=0, cat=CAT_PHASE) == \
                 rep.total_cycles
             t = block_traffic(spec, hw, hw)
-            if sched == CFUSchedule.LAYER_DRAM:
-                h2, w2 = spec.out_hw(hw, hw)
-                t2 = block_traffic(spec, h2, w2)
-                want = t.baseline_total + t2.baseline_total
-                assert m.report(1).dram_bytes == want
+            if sched == CFUSchedule.LAYER_DRAM:   # one block: Eq. 1
+                assert m.report(1).dram_bytes == t.baseline_total
         else:
             m = MultiStreamCostModel(prog, "v3")
             rep = m.report(batch)

@@ -26,6 +26,10 @@ SPECS = [
     (DSCBlockSpec(cin=8, cmid=48, cout=16, stride=2), 12),    # downsample
     (DSCBlockSpec(cin=16, cmid=96, cout=16, stride=1), 10),   # paper 5th
     (DSCBlockSpec(cin=8, cmid=24, cout=8, stride=1), 7),      # odd H/W
+    # t=1, no expansion: the depthwise reads the input
+    (DSCBlockSpec(cin=8, cmid=8, cout=8, stride=1), 9),       # residual
+    (DSCBlockSpec(cin=8, cmid=8, cout=16, stride=2), 11),     # s2, odd W
+    (DSCBlockSpec(cin=32, cmid=32, cout=16, stride=1), 7),    # MNV2 first
 ]
 
 
@@ -78,3 +82,37 @@ def test_pipeline_register_state_is_bounded():
     carry_sizes = [int(np.prod(v.aval.shape))
                    for v in eq.invars[nc:nc + nk]]
     assert sum(carry_sizes) == 3 * 3 * spec.cmid + spec.cmid
+
+
+def test_block_without_expansion_has_no_expansion_weights():
+    """t=1 (cmid == cin) is MobileNetV2's published first bottleneck: no
+    1x1 expansion, so no expansion weights and F1 in the input's domain;
+    its depthwise pads with the input's zero point."""
+    spec = DSCBlockSpec(cin=8, cmid=8, cout=16, stride=2)
+    assert not spec.has_expansion
+    assert DSCBlockSpec(cin=8, cmid=16, cout=8).has_expansion
+    assert spec.macs(6, 6)["expansion"] == 0
+    x_q, qp = _block(spec, 6)
+    assert qp.w_exp is None and qp.b_exp is None and qp.m_exp is None
+    assert qp.qp_f1 == qp.qp_in
+    assert dsc.f1_zero_point(qp) == qp.qp_in.zero_point
+    # the reference's depthwise sees exactly the zero-point-padded input
+    f1 = np.pad(np.asarray(x_q), ((1, 1), (1, 1), (0, 0)),
+                constant_values=qp.qp_in.zero_point).astype(np.int64)
+    w = np.asarray(qp.w_dw, np.int64)
+    acc = sum(f1[dy:dy + 5:2, dx:dx + 5:2] * w[dy, dx]
+              for dy in range(3) for dx in range(3)) + np.asarray(qp.b_dw)
+    f2 = np.asarray(quant.requantize(jnp.asarray(acc, jnp.int32), qp.m_dw,
+                                     qp.qp_f2.zero_point, relu=True,
+                                     relu6_max_q=qp.q6_f2))
+    y = quant.requantize(dsc._projection_acc(jnp.asarray(f2), qp),
+                         qp.m_proj, qp.qp_out.zero_point)
+    np.testing.assert_array_equal(np.asarray(y),
+                                  np.asarray(dsc.dsc_block_reference(x_q,
+                                                                     qp)))
+
+
+@pytest.mark.parametrize("cin,cmid", [(8, 7), (16, 8)])
+def test_expansion_never_shrinks(cin, cmid):
+    with pytest.raises(ValueError, match="expands"):
+        DSCBlockSpec(cin=cin, cmid=cmid, cout=8)

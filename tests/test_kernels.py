@@ -83,6 +83,33 @@ def test_fused_dsc_vww_blocks_exact_vs_reference(name, spec, hw):
                                   np.asarray(dsc.dsc_block_reference(x_q, qp)))
 
 
+@pytest.mark.parametrize("spec,hw,tile_rows", [
+    (DSCBlockSpec(cin=8, cmid=8, cout=8, stride=1), 9, 4),     # residual
+    (DSCBlockSpec(cin=8, cmid=8, cout=16, stride=2), 11, 4),   # odd W, h2=6
+    (DSCBlockSpec(cin=16, cmid=16, cout=8, stride=1), 7, 3),   # h2=7 ragged
+    (DSCBlockSpec(cin=136, cmid=136, cout=24, stride=2), 9, 2),  # 2 chunks
+])
+def test_dw_block_exact_vs_reference(spec, hw, tile_rows):
+    """A block without expansion (t=1) through its own entry, the same
+    kernel body with the expansion left out: F1 is the input, padded with
+    the input's zero point, bit-exact to the layer-by-layer reference."""
+    p32 = dsc.init_dsc_block_f32(jax.random.PRNGKey(5), spec)
+    calib = np.asarray(jax.random.normal(jax.random.PRNGKey(6),
+                                         (hw, hw, spec.cin)))
+    qp = dsc.quantize_dsc_block(p32, spec, calib)
+    x_q = jnp.asarray(quant.quantize(calib, qp.qp_in))
+    got = ops.dw_block(x_q, qp.w_dw.reshape(9, spec.cmid), qp.w_proj,
+                       qp.b_dw, qp.b_proj, qp.m_dw, qp.m_proj,
+                       stride=spec.stride,
+                       zps=(qp.qp_in.zero_point, qp.qp_f2.zero_point,
+                            qp.qp_out.zero_point),
+                       q6=qp.q6_f2, tile_rows=tile_rows, interpret=True)
+    if spec.has_residual:
+        got = dsc.residual_add_q(got, x_q, qp)
+    np.testing.assert_array_equal(np.asarray(got),
+                                  np.asarray(dsc.dsc_block_reference(x_q, qp)))
+
+
 # --- fused FFN --------------------------------------------------------------
 
 

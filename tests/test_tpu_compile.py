@@ -7,7 +7,12 @@ cannot show that a kernel is legal on the chip. These tests compile for a
 * ``fused_dsc_pallas`` at every VWW block shape (the seven PAPER_BLOCKS at
   their feature-map sizes, stride 2 and the 10x10 / 5x5 maps included);
 * the whole 80x80 VWW fast-path chain at batch 8 with Pallas stage bodies,
-  i.e. ``vmap`` over the ``pallas_call``.
+  i.e. ``vmap`` over the ``pallas_call``;
+* the kernel of a block without expansion (``dw_pallas``), at
+  MobileNetV2's first block (112x112x32) and at a stride-2 odd map;
+* the whole 224x224 MobileNetV2 1.0 chain of the benchmark's
+  ``mnv2-224-fused`` configuration at batch 8: 16 expanding kernels, one
+  without expansion, 112x112 maps, a 1280-channel head.
 
 Each compiled program must contain a ``tpu_custom_call`` (a compiled
 kernel, not an interpreted one). One more test pins where the persistent
@@ -65,8 +70,10 @@ def compiled_kernels(monkeypatch):
     interpreted trace is reused here and no compiled one leaks out."""
     monkeypatch.setattr(kops, "default_interpret", lambda: False)
     kops.dsc_block.clear_cache()
+    kops.dw_block.clear_cache()
     yield
     kops.dsc_block.clear_cache()
+    kops.dw_block.clear_cache()
 
 
 @pytest.mark.parametrize("name,spec,hw", VWW_BLOCKS,
@@ -90,6 +97,84 @@ def test_fused_dsc_compiles_for_v5e(one_chip, name, spec, hw):
 
     text = jax.jit(block).lower(*args).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("cin,cout,stride,hw", [(32, 16, 1, 112),
+                                               (8, 16, 2, 11)])
+def test_dw_block_compiles_for_v5e(one_chip, cin, cout, stride, hw):
+    from repro.kernels.fused_dsc import dw_pallas
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = [sds((hw, hw, cin), jnp.int8), sds((9, cin), jnp.int8),
+            sds((cin, cout), jnp.int8), sds((cin,), jnp.int32),
+            sds((cout,), jnp.int32), sds((cin,), jnp.float32),
+            sds((cout,), jnp.float32)]
+
+    def block(*a):
+        return dw_pallas(*a, stride=stride, zps=(-128, -128, 5), q6=90,
+                         tile_rows=4, interpret=False)
+
+    text = jax.jit(block).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+def _zero_params(cfg):
+    """Stage records of the configuration's shapes, all zero: a compile
+    needs shapes and quantization constants, not values."""
+    from repro.cfu.network import CFUFCParams, CFUHeadParams, CFUStemParams
+    from repro.core.dsc import DSCBlockSpec, QuantizedDSCParams
+    from repro.core.quant import QParams
+
+    def z(*shape, dt=np.int8):
+        return np.zeros(shape, dt)
+    q6, lin = QParams(0.03125, -128), QParams(0.0625, 0)
+    c0, hc, nc = cfg["blocks"][0][1], cfg["head_ch"], cfg["n_classes"]
+    specs = []
+    params = [CFUStemParams(z(3, 3, cfg["img_ch"], c0), z(c0, dt=np.int32),
+                            z(c0, dt=np.float32), QParams(1 / 128, 0), q6,
+                            64)]
+    for name, ci, cm, co, s in cfg["blocks"]:
+        spec = DSCBlockSpec(ci, cm, co, s)
+        specs.append((name, spec))
+        e = spec.has_expansion
+        params.append(QuantizedDSCParams(
+            spec, z(ci, cm) if e else None, z(3, 3, cm), z(cm, co),
+            z(cm, dt=np.int32) if e else None, z(cm, dt=np.int32),
+            z(co, dt=np.int32), q6 if len(params) == 1 else lin, q6, q6,
+            lin, z(cm, dt=np.float32) if e else None, z(cm, dt=np.float32),
+            z(co, dt=np.float32), 64, 64))
+    c_last = cfg["blocks"][-1][3]
+    params += [CFUHeadParams(z(c_last, hc), z(hc, dt=np.int32),
+                             z(hc, dt=np.float32), lin, q6, 64),
+               CFUFCParams(z(hc, nc), z(nc, dt=np.int32),
+                           z(nc, dt=np.float32), lin)]
+    return specs, params
+
+
+def test_mnv2_224_chain_compiles_for_v5e(one_chip, compiled_kernels):
+    import json
+    import pathlib
+    from repro.cfu.compiler import compile_vww_network
+    from repro.cfu.fastpath import FastPathExecutor
+    cfg = json.loads((pathlib.Path(__file__).resolve().parents[1] /
+                      "chipbench/configs/mnv2-224-fused.json").read_text())
+    specs, params = _zero_params(cfg)
+    prog = compile_vww_network(specs, cfg["img_hw"], cfg["schedule"],
+                               img_ch=cfg["img_ch"], head_ch=cfg["head_ch"],
+                               n_classes=cfg["n_classes"])
+    ex = FastPathExecutor(prog, params, use_pallas=True)
+    assert [st.kind for st in ex.stages].count("dw") == 1
+
+    def sds(a):
+        a = np.asarray(a)
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    x = jax.ShapeDtypeStruct((8, 224, 224, 3), jnp.int8, sharding=one_chip)
+    weights = jax.tree.map(sds, ex.weights_of(params))
+    text = ex.jitted.lower(x, weights).compile().as_text()
+    assert text.count("tpu_custom_call") >= len(cfg["blocks"])
 
 
 @pytest.fixture(scope="module")
