@@ -509,6 +509,32 @@ def _build_stage_fn(stage: _Stage, p, use_pallas: bool):
 
 _DTYPES = {"w": np.int8, "b": np.int32, "m": np.float32}   # by name prefix
 
+# A large call is uploaded and run in chunks, so that the chain starts on
+# the first while the others are in transit. Two limits keep a chunk
+# worth it. It moves at least _CHUNK_MIN_BYTES: on a TPU v5e the fast
+# path's readback times fit 1.1 ms + bytes / 5.3 GB/s (4.9 MB a call at
+# 80x80, 38.5 MB at 224x224, batch 256), so 8 MiB is about 1.6 ms of
+# transfer, more than the host takes to dispatch one chunk's chain. And
+# it holds a multiple of _CHUNK_IMAGES images: XLA:TPU lays an int8
+# batch of 224x224x3 images out with the batch in the 128 lanes at 128,
+# 256, 384 or 512 images, but with the width there at 32, 64 or 192,
+# which turns the stem's nine stride-2 taps into lane-strided slices of
+# about 3 ms a call each on a v5e (64-image chunks of a 256-image call).
+_CHUNK_MIN_BYTES = 8 << 20
+_CHUNK_IMAGES = 128
+
+
+def _chunk_count(n: int, image_bytes: int) -> int:
+    """The equal chunks a batch of ``n`` images of ``image_bytes`` each is
+    uploaded and run in: the largest divisor of ``n`` whose chunks each
+    hold a multiple of ``_CHUNK_IMAGES`` images and move at least
+    ``_CHUNK_MIN_BYTES``, else 1."""
+    for k in range(min(n // _CHUNK_IMAGES,
+                       n * image_bytes // _CHUNK_MIN_BYTES), 1, -1):
+        if n % (k * _CHUNK_IMAGES) == 0:
+            return k
+    return 1
+
 
 # --------------------------------------------------------------------------
 # The executor object + fingerprint cache
@@ -539,25 +565,40 @@ class FastPathExecutor:
     ``weight_uploads`` counts the arrays uploaded or converted over the
     executor's life, ``weight_binds`` the calls that had any.
 
+    A large batch is uploaded and run in K equal chunks (``_chunk_count``
+    of the batch and the bytes an image: the most chunks that each hold
+    a multiple of 128 images and move at least 8 MiB). The chain of one
+    chunk waits only for that chunk's input, so it runs while the next
+    is in transit; each image's logits depend on that image alone, so
+    the result is the same. A 256-image batch of 224x224x3 (38.5 MB)
+    goes in 2 chunks; a VWW batch (4.9 MB), small batches and single
+    frames go whole (K = 1), as one upload and one dispatch.
+
     Each call runs four phases, each inside a ``jax.profiler``
     annotation on the profiler's clock (the clock of the device's
-    ``XLA Ops``), once per call and in this order:
+    ``XLA Ops``), in this order:
 
     * ``fastpath.weights``: the stage arrays bound as above, without
       uploading (args ``d2h_arrays``, always 0, ``reused`` and
       ``uploaded``, the arrays this call uploads or converts);
-    * ``fastpath.put_input``: the input checked and uploaded (``bytes``);
+    * ``fastpath.put_input``: one chunk of the input uploaded (``bytes``
+      of the chunk, ``chunks`` = K);
     * ``fastpath.launch``: the uploads the binding asked for, if any
-      (``arrays``, ``bytes``; 0 once bound), then the jitted chain
-      dispatched;
-    * ``fastpath.readback``: the logits copied back, which waits for the
-      device.
+      (``arrays``, ``bytes``; in the first chunk only, 0 once bound),
+      then the jitted chain dispatched on that chunk;
+    * ``fastpath.readback``: the logits of every chunk copied back
+      together, which waits for the device, and joined.
+
+    ``put_input`` and ``launch`` alternate once per chunk, so no chain
+    waits behind the upload of a later chunk, and a call with K = 1 holds
+    each phase once. ``chunked_calls`` counts the calls with
+    K > 1, and ``n_traces`` the chunk shapes compiled.
 
     The input goes up through a donated identity ``jit``, which on a TPU
     v5e costs about 0.2 ms a call less than ``jax.device_put``. Each
-    dispatch that compiles, those of the first call with a new input
-    shape, runs inside a nested ``fastpath.compile``. With no profiler
-    running an annotation costs about a microsecond.
+    dispatch that compiles, those of the first chunk of the first call
+    with a new chunk shape, runs inside a nested ``fastpath.compile``.
+    With no profiler running an annotation costs about a microsecond.
     """
 
     def __init__(self, prog, params: Sequence,
@@ -608,16 +649,17 @@ class FastPathExecutor:
         self._bound: List[Optional[Tuple]] = [None] * len(self._slots)
         self.weight_uploads = 0
         self.weight_binds = 0
+        self.chunked_calls = 0
 
     @property
     def n_traces(self) -> int:
-        """The distinct input batch shapes this executor has compiled."""
+        """The distinct input chunk shapes this executor has compiled."""
         return len(self._shapes)
 
     @staticmethod
     def _dispatch(fn, new: bool, *args):
         """``fn(*args)``; inside a ``fastpath.compile`` span when ``new``
-        (the first call with an input shape compiles ``fn`` for it)."""
+        (the first chunk with a new shape compiles ``fn`` for it)."""
         if not new:
             return fn(*args)
         import jax
@@ -690,23 +732,32 @@ class FastPathExecutor:
     def __call__(self, x_q, params: Sequence) -> np.ndarray:
         import jax
         span = jax.profiler.TraceAnnotation
+        x_q, batched = bind_input(x_q, self.meta)
+        parts = np.split(x_q, _chunk_count(len(x_q), x_q[0].nbytes))
+        new = parts[0].shape not in self._shapes
         with span("fastpath.weights") as s:
             flat, todo = self._plan(params)
             s.set_metadata(d2h_arrays=0, reused=len(flat) - len(todo),
                            uploaded=len(todo))
-        with span("fastpath.put_input") as s:
-            x_q, batched = bind_input(x_q, self.meta)
-            s.set_metadata(bytes=x_q.nbytes)
-            new = x_q.shape not in self._shapes
-            x_dev = self._dispatch(self._put, new, x_q)
-        with span("fastpath.launch") as s:
-            s.set_metadata(arrays=len(todo),
-                           bytes=self._upload(flat, todo))
-            y = self._dispatch(self.jitted, new, x_dev, self._nest(flat))
-            self._shapes.add(x_q.shape)
+        ys = []
+        # chunk i + 1 goes up after chain i is queued, never before it:
+        # 0.6 ms a call faster than all uploads first (v5e, 2 chunks)
+        for i, part in enumerate(parts):
+            with span("fastpath.put_input") as s:
+                s.set_metadata(bytes=part.nbytes, chunks=len(parts))
+                x_dev = self._dispatch(self._put, new and not i, part)
+            with span("fastpath.launch") as s:
+                s.set_metadata(arrays=len(todo),
+                               bytes=self._upload(flat, todo))
+                todo = []                 # bound: later chunks reuse
+                ys.append(self._dispatch(self.jitted, new and not i, x_dev,
+                                         self._nest(flat)))
+        self._shapes.add(parts[0].shape)
+        self.chunked_calls += len(parts) > 1
         with span("fastpath.readback"):
             out_shape = tuple(self.meta["out_shape"])
-            y = np.asarray(y).reshape((x_q.shape[0],) + out_shape)
+            y = np.concatenate(jax.device_get(ys)).reshape(
+                (len(x_q),) + out_shape)
             return y if batched else y[0]
 
 

@@ -394,6 +394,34 @@ def test_run_fast_rejects_bad_input_shape():
         fastpath.run_fast(prog, np.zeros((HW, HW), np.int8), params)
 
 
+# --- the chunk plan of a large call ------------------------------------------
+
+IMG80, IMG224 = 80 * 80 * 3, 224 * 224 * 3
+
+
+@pytest.mark.parametrize("n, image_bytes, k", [
+    (256, IMG80, 1),          # VWW batch, 4.9 MB: one put, one dispatch
+    (256, IMG224, 2),         # 38.5 MB: two chunks of 128 images, 19.3 MB
+    (1, IMG224, 1),           # a single frame
+    (8, IMG224, 1),           # a spot-check batch
+    (250, IMG224, 1),         # no divisor holds a multiple of 128 images
+    (384, IMG224, 3),         # three of 128
+    (1024, IMG224, 8),        # eight of 128
+    (2048, IMG80, 4),         # 512 images of 80x80 to pass the byte floor
+])
+def test_chunk_plan(n, image_bytes, k):
+    got = fastpath._chunk_count(n, image_bytes)
+    assert got == k
+    assert n % got == 0
+
+    def fits(j):              # j chunks of whole lanes, each over the floor
+        m = n // j
+        return (n % j == 0 and m % fastpath._CHUNK_IMAGES == 0
+                and m * image_bytes >= fastpath._CHUNK_MIN_BYTES)
+    assert got == 1 or fits(got)
+    assert not any(fits(j) for j in range(got + 1, n + 1))
+
+
 # --- the fast spot-check backend stays anchored ------------------------------
 
 
