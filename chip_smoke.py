@@ -10,8 +10,9 @@ widths + head 128 + GAP + FC; random weights from ``--seed``):
 
 1. device check: the first JAX device must be a TPU;
 2. fast path: the whole network compiled under ``fused``,
-   ``fused-rowtile`` (Pallas stage bodies), ``layer-dram`` (reference body)
-   and ``fused-winograd`` (jnp winograd body), run through
+   ``fused-rowtile`` (Pallas stage bodies), ``layer-dram`` and
+   ``fused-winograd`` (reference body; fused-winograd lowers its stride-2
+   blocks fused, so those run the kernel), run through
    ``fastpath.FastPathExecutor`` at batch 1 and batch 8 on seeded images;
 3. bit-exact check: every output equals the host-side golden word
    interpreter's (``executor.run_program``, pure numpy) under ``==``. The

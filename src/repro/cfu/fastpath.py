@@ -21,47 +21,31 @@ has to recognise which network-level stage a CFG unit implements — the
 instruction kinds are unambiguous:
 
 * ``CONV_MAC``                      -> 3x3 stem conv
-* ``DW_MAC``                        -> DSC block (residual iff ``RES_ADD``);
-                                       without an EXP weight load, a
-                                       block without expansion (t=1)
-* ``WINO_MAC``                      -> DSC block, winograd depthwise body
+* ``DW_MAC``                        -> DSC block; without an EXP weight
+                                       load, a block without expansion
+                                       (t=1)
+* ``WINO_MAC``                      -> DSC block (fused-winograd)
 * ``GAP_RST``                       -> GAP + FC classifier unit
 * ``EXP_MAC``-only                  -> head 1x1 conv
 
-The fused-winograd schedule gets its own jnp stage body (used for BOTH
-backends — there is no Pallas winograd kernel): the identical folded
-integer F(2x2,3x3) transform of ``cfu.winograd``, batched over the tile
-grid with strided slices, exact by the same argument as the interpreter
-(the transform IS integer arithmetic; the elementwise stage runs in
-int32 well under the statically-checked accumulator bound).
-
 and then reuse arithmetic that is ALREADY proven bit-exact against the
-interpreter: ``kernels/fused_dsc.py`` for fused/rowtile DSC blocks (the
-paper's zero-buffer dataflow on the TPU memory hierarchy),
-``core.dsc.dsc_block_reference`` for layer-schedule blocks, and the same
-int8 ops ``models.mobilenetv2.forward_int8`` uses for stem / head /
-GAP / FC. Integer accumulation plus the shared float32 requantization
-sequence make every reused op bit-identical by construction.
+interpreter. A DSC stage gets one of two bodies, chosen from the lifted
+program and the platform alone:
 
-Backend-adaptive stage bodies (and why they stay exact)
--------------------------------------------------------
-On a real TPU the Pallas kernels compile natively and ``jax.vmap`` maps
-the batch axis onto hardware, so the traced chain calls
-``kernels.ops.dsc_block`` directly. On CPU Pallas runs in *interpret*
-mode — the kernel body executes per grid step inside the trace, and vmap
-SERIALIZES the batch — so there the chain uses a jnp twin of the same
-stage arithmetic that XLA:CPU can actually vectorize. The twin's only
-liberty is evaluating int8 matmuls in float32 where that is provably
-exact: every int8 x int8 product is an integer of magnitude <= 128^2,
-a K-term dot is an integer of magnitude <= K * 128^2, and float32
-represents every integer up to 2^24 exactly — so while
-``K * 128^2 < 2^24`` (K <= 1023; the VWW network's largest contraction
-is 576) the SGEMM result cast back to int32 is bit-identical to integer
-accumulation. Contractions beyond the bound fall back to int32 einsum
-at trace-build time (a static shape check, not a runtime branch). The
-backend choice is part of the cache key, ``use_pallas`` can be forced
-either way, and both bodies are differentially pinned against the
-interpreter by the same matrix tests.
+* the Pallas kernel (``kernels.ops.dsc_block_q``, the paper's zero-buffer
+  dataflow on the TPU memory hierarchy) for a kernel unit, fused (no
+  ``ST_VEC``) or rowtile (a ``CFG_STRIP``), where Pallas compiles
+  natively: ``use_pallas``, true by default on a TPU;
+* ``core.dsc.dsc_block_reference`` everywhere else: the layer schedules
+  and fused-winograd on every backend, and every DSC stage where Pallas
+  would only interpret (there the kernel body runs per grid step and
+  ``vmap`` serializes the batch).
+
+Stem, head and GAP / FC run the int8 ops of
+``models.mobilenetv2.forward_int8``, with int8 matmuls in float32 where
+that is provably exact (``_f32_gemm_exact``). Integer accumulation plus
+the shared float32 requantization sequence make every reused op
+bit-identical by construction. ``use_pallas`` is part of the cache key.
 
 Cache key semantics
 -------------------
@@ -96,7 +80,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from repro.cfu import isa
-from repro.cfu import winograd
 from repro.cfu.executor import bind_input, read_output
 
 __all__ = [
@@ -161,10 +144,9 @@ class _Stage:
     stride: int
     h: int
     w: int
-    residual: bool = False
-    impl: str = ""       # dsc: "pallas" (fused/rowtile) | "reference"
-    tile_rows: int = 4   # dsc pallas granularity (from CFG_STRIP if set)
-    gap_n: int = 0       # gapfc divisor (GAP_FIN operand)
+    kernel: bool = False  # dsc/dw: a kernel unit (fused or rowtile)
+    tile_rows: int = 4    # kernel granularity (from CFG_STRIP if set)
+    gap_n: int = 0        # gapfc divisor (GAP_FIN operand)
 
     def out_shape(self, in_shape: Tuple[int, ...]) -> Tuple[int, ...]:
         h2, w2 = -(-self.h // self.stride), -(-self.w // self.stride)
@@ -191,7 +173,6 @@ def _lift_stream(instrs: Sequence[isa.Instr]) -> List[_Stage]:
                 cin, cmid, cout = isa.widen_cfg(cin, cmid, cout, ins.args)
         ops = {i.op for i in unit}
         wgt = {i.args[0]: i.args[1] for i in unit if i.op == "LD_WGT"}
-        residual = "RES_ADD" in ops
         if "CONV_MAC" in ops:
             stages.append(_Stage("stem", wgt[isa.WGT_CONV], cin, cmid,
                                  cout, stride, h, w))
@@ -203,26 +184,21 @@ def _lift_stream(instrs: Sequence[isa.Instr]) -> List[_Stage]:
             strip = next((i.args[0] for i in unit if i.op == "CFG_STRIP"),
                          0)
             if strip:                    # rowtile: invert (t-1)*s + k
-                impl, tr = "pallas", max(1, (strip - isa.KERNEL) // stride
-                                         + 1)
-            elif "ST_VEC" not in ops:    # fused pixel-wise: nothing stored
-                impl, tr = "pallas", 4
-            else:                        # layer-dram / layer-sram
-                impl, tr = "reference", 4
+                kernel, tr = True, max(1, (strip - isa.KERNEL) // stride
+                                       + 1)
+            else:                        # fused pixel-wise stores nothing;
+                kernel, tr = "ST_VEC" not in ops, 4   # layer-* store F1/F2
             if isa.WGT_EXP in wgt:
                 kind, block = "dsc", wgt[isa.WGT_EXP]
             else:                        # t=1: no expansion weights
                 kind, block = "dw", wgt[isa.WGT_DW]
             stages.append(_Stage(kind, block, cin, cmid, cout, stride, h,
-                                 w, residual=residual, impl=impl,
-                                 tile_rows=tr))
+                                 w, kernel=kernel, tile_rows=tr))
         elif "WINO_MAC" in ops:
             # fused-winograd: no DW_MAC/LD_WIN in the stream, so this must
             # be checked before the EXP_MAC-only head classification
             stages.append(_Stage("dsc", wgt[isa.WGT_EXP], cin, cmid, cout,
-                                 stride, h, w, residual=residual,
-                                 impl="winograd",
-                                 tile_rows=winograd.TILE))
+                                 stride, h, w))
         elif "EXP_MAC" in ops:
             stages.append(_Stage("head", wgt[isa.WGT_EXP], cin, cmid,
                                  cout, stride, h, w))
@@ -368,142 +344,20 @@ def _build_stage_fn(stage: _Stage, p, use_pallas: bool):
             return quant.requantize(acc, w["m_proj"], zp_out)
         return gapfc_fn
 
-    # --- DSC block ---------------------------------------------------------
-    if stage.impl == "winograd":
-        # Same folded integer F(2x2,3x3) as executor._op_wino_mac, batched
-        # over the whole tile grid with strided slices. Used for BOTH
-        # backends — there is no Pallas winograd kernel; the transform is
-        # a handful of tiny integer contractions XLA fuses fine. Exactness
-        # is the interpreter's argument verbatim: every intermediate is
-        # bounded by winograd.accumulator_bound() << 2^31, and Y4 is a
-        # multiple of 4, so the floor division is exact.
-        zp_f1 = p.qp_f1.zero_point
-        zp_f2, zp_out = p.qp_f2.zero_point, p.qp_out.zero_point
-        q6_f1, q6_f2 = p.q6_f1, p.q6_f2
-        residual, p0 = stage.residual, p
-        cin, cmid, cout = stage.cin, stage.cmid, stage.cout
-        bt = jnp.asarray(winograd.BT, jnp.int32)
-        g2 = jnp.asarray(winograd.G2, jnp.int32)
-        at = jnp.asarray(winograd.AT, jnp.int32)
-
-        def dsc_wino_fn(x, w):
-            h, wd = x.shape[0], x.shape[1]
-            acc = mm(x.reshape(h * wd, cin), w["w_exp"],
-                     cin).reshape(h, wd, cmid) + w["b_exp"]
-            f1 = quant.requantize(acc, w["m_exp"], zp_f1, relu=True,
-                                  relu6_max_q=q6_f1)
-            h2, w2 = h, wd                       # stride 1 by construction
-            ty, tx = -(-h2 // 2), -(-w2 // 2)
-            # zp_f1 halo + right/bottom overhang padding to an even tile
-            # grid — identical to the reference's padded F1 (overhang taps
-            # fall outside the map, which IS the zero-point fill)
-            f1p = jnp.pad(f1, ((1, 1 + 2 * ty - h2), (1, 1 + 2 * tx - w2),
-                               (0, 0)), constant_values=zp_f1)
-            taps = [jax.lax.slice(
-                f1p, (dy, dx, 0),
-                (dy + 2 * (ty - 1) + 1, dx + 2 * (tx - 1) + 1, cmid),
-                (2, 2, 1)) for dy in range(4) for dx in range(4)]
-            d = jnp.stack(taps, axis=2).reshape(ty, tx, 4, 4, cmid)
-            d = d.astype(jnp.int32)
-            u4 = jnp.einsum("ij,jkc,lk->ilc", g2,
-                            w["w_dw"].astype(jnp.int32), g2)
-            v = jnp.einsum("ij,yxjkc,lk->yxilc", bt, d, bt)
-            y4 = jnp.einsum("ij,yxjkc,lk->yxilc", at, v * u4, at)
-            tiles = y4 // 4                      # exact: y4 = 4 * conv
-            full = tiles.transpose(0, 2, 1, 3, 4).reshape(
-                2 * ty, 2 * tx, cmid)[:h2, :w2]
-            f2 = quant.requantize(full + w["b_dw"], w["m_dw"], zp_f2,
-                                  relu=True, relu6_max_q=q6_f2)
-            acc = mm(f2.reshape(h2 * w2, cmid), w["w_proj"],
-                     cmid).reshape(h2, w2, cout) + w["b_proj"]
-            y = quant.requantize(acc, w["m_proj"], zp_out)
-            if residual:
-                y = dsc_mod.residual_add_q(y, x, p0)
-            return y
-        return dsc_wino_fn
-
-    if not use_pallas:
-        # jnp twin of the block arithmetic (identical stage semantics to
-        # dsc_block_reference, matmuls in f32 where exact) — XLA:CPU
-        # vectorizes this across the vmap batch; interpret-mode Pallas
-        # cannot.
-        expand = stage.kind == "dsc"
-        zp_f1 = dsc_mod.f1_zero_point(p)
-        zp_f2, zp_out = p.qp_f2.zero_point, p.qp_out.zero_point
-        q6_f1, q6_f2 = p.q6_f1, p.q6_f2
-        s, residual, p0 = stage.stride, stage.residual, p
-        cin, cmid, cout = stage.cin, stage.cmid, stage.cout
-        dw_exact = _f32_gemm_exact(9)
-
-        def dsc_jnp_fn(x, w):
-            h, wd = x.shape[0], x.shape[1]
-            f1 = x                       # t=1: F1 is the input
-            if expand:
-                acc = mm(x.reshape(h * wd, cin), w["w_exp"],
-                         cin).reshape(h, wd, cmid) + w["b_exp"]
-                f1 = quant.requantize(acc, w["m_exp"], zp_f1, relu=True,
-                                      relu6_max_q=q6_f1)
-            f1p = jnp.pad(f1, ((1, 1), (1, 1), (0, 0)),
-                          constant_values=zp_f1)
-            h2, w2 = -(-h // s), -(-wd // s)
-            dw_dt = jnp.float32 if dw_exact else jnp.int32
-            wdw = w["w_dw"].reshape(9, cmid).astype(dw_dt)
-            acc = jnp.zeros((h2, w2, cmid), dw_dt)
-            for dy in range(3):
-                for dx in range(3):
-                    win = jax.lax.slice(
-                        f1p, (dy, dx, 0),
-                        (dy + (h2 - 1) * s + 1, dx + (w2 - 1) * s + 1,
-                         cmid), (s, s, 1))
-                    acc = acc + win.astype(dw_dt) * wdw[dy * 3 + dx]
-            acc = acc.astype(jnp.int32) + w["b_dw"]
-            f2 = quant.requantize(acc, w["m_dw"], zp_f2, relu=True,
-                                  relu6_max_q=q6_f2)
-            acc = mm(f2.reshape(h2 * w2, cmid), w["w_proj"],
-                     cmid).reshape(h2, w2, cout) + w["b_proj"]
-            y = quant.requantize(acc, w["m_proj"], zp_out)
-            if residual:
-                y = dsc_mod.residual_add_q(y, x, p0)
-            return y
-        return dsc_jnp_fn
-
-    if stage.impl == "pallas":
+    # --- DSC block: the kernel on a kernel unit where Pallas compiles
+    # natively, else the layer-by-layer reference; either way the block's
+    # params with the weight tensors swapped for the traced arguments
+    if use_pallas and stage.kernel:
         from repro.kernels import ops as kops
-        zps = (p.qp_in.zero_point, p.qp_f1.zero_point,
-               p.qp_f2.zero_point, p.qp_out.zero_point)
-        q6 = (p.q6_f1, p.q6_f2)
-        stride, tile_rows, residual = stage.stride, stage.tile_rows, \
-            stage.residual
-        cmid, p0 = stage.cmid, p
+        tile_rows = stage.tile_rows
 
-        def dsc_pallas_fn(x, w):
-            y = kops.dsc_block(
-                x, w["w_exp"], w["w_dw"].reshape(9, cmid), w["w_proj"],
-                w["b_exp"], w["b_dw"], w["b_proj"],
-                w["m_exp"], w["m_dw"], w["m_proj"],
-                stride=stride, zps=zps, q6=q6, tile_rows=tile_rows)
-            if residual:
-                y = dsc_mod.residual_add_q(y, x, p0)
-            return y
-
-        def dw_pallas_fn(x, w):
-            y = kops.dw_block(
-                x, w["w_dw"].reshape(9, cmid), w["w_proj"], w["b_dw"],
-                w["b_proj"], w["m_dw"], w["m_proj"], stride=stride,
-                zps=(zps[0], zps[2], zps[3]), q6=q6[1],
-                tile_rows=tile_rows)
-            if residual:
-                y = dsc_mod.residual_add_q(y, x, p0)
-            return y
-        return dsc_pallas_fn if stage.kind == "dsc" else dw_pallas_fn
-
-    p0, names = p, _STAGE_ARRAYS[stage.kind]
+        def dsc_kernel_fn(x, w):
+            return kops.dsc_block_q(x, dataclasses.replace(p, **w),
+                                    tile_rows=tile_rows)
+        return dsc_kernel_fn
 
     def dsc_ref_fn(x, w):
-        # same stage arithmetic as the layer-by-layer oracle, with the
-        # weight tensors swapped for the traced arguments
-        pt = dataclasses.replace(p0, **{k: w[k] for k in names})
-        return dsc_mod.dsc_block_reference(x, pt)
+        return dsc_mod.dsc_block_reference(x, dataclasses.replace(p, **w))
     return dsc_ref_fn
 
 
@@ -797,8 +651,8 @@ def _evict_to_limit() -> None:
 
 
 def _resolve_use_pallas(flag: Optional[bool]) -> bool:
-    """Default: Pallas stage bodies only where they compile natively
-    (TPU); in interpret mode the jnp twin is the vectorizable choice."""
+    """Default: kernel stage bodies only where Pallas compiles natively
+    (TPU); elsewhere every DSC stage runs the reference body."""
     if flag is not None:
         return bool(flag)
     from repro.kernels import ops as kops

@@ -16,6 +16,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core.dsc import QuantizedDSCParams, residual_add_q
 from repro.kernels import fused_dsc as _dsc
 from repro.kernels import fused_ffn as _ffn
 from repro.kernels import flash_attention as _fa
@@ -55,6 +56,33 @@ def dw_block(x_q, w_dw9, w_proj, b_dw, b_proj, m_dw, m_proj, *,
     return _dsc.dw_pallas(
         x_q, w_dw9, w_proj, b_dw, b_proj, m_dw, m_proj, stride=stride,
         zps=zps, q6=q6, tile_rows=tile_rows, interpret=interp)
+
+
+def dsc_block_q(x_q, p: QuantizedDSCParams, *, tile_rows: int = 4):
+    """One int8 inverted-residual block through its kernel: ``dsc_block``,
+    or ``dw_block`` for a block without expansion, then the residual add
+    where the block has one.
+
+    Not jitted itself, so the device trace keeps naming the kernels
+    ``jit_dsc_block`` and ``jit_dw_block``."""
+    spec = p.spec
+    w_dw9 = p.w_dw.reshape(9, spec.cmid)
+    zp_in, zp_f2, zp_out = (p.qp_in.zero_point, p.qp_f2.zero_point,
+                            p.qp_out.zero_point)
+    if spec.has_expansion:
+        y = dsc_block(
+            x_q, p.w_exp, w_dw9, p.w_proj, p.b_exp, p.b_dw, p.b_proj,
+            p.m_exp, p.m_dw, p.m_proj, stride=spec.stride,
+            zps=(zp_in, p.qp_f1.zero_point, zp_f2, zp_out),
+            q6=(p.q6_f1, p.q6_f2), tile_rows=tile_rows)
+    else:
+        y = dw_block(
+            x_q, w_dw9, p.w_proj, p.b_dw, p.b_proj, p.m_dw, p.m_proj,
+            stride=spec.stride, zps=(zp_in, zp_f2, zp_out), q6=p.q6_f2,
+            tile_rows=tile_rows)
+    if spec.has_residual:
+        y = residual_add_q(y, x_q, p)
+    return y
 
 
 # --- fused FFN -------------------------------------------------------------

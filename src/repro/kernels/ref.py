@@ -1,9 +1,10 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
+"""Pure-jnp oracles for the float Pallas kernels in this package.
 
 Each function is the semantic ground truth its kernel is swept against in
-tests/test_kernels.py (shape/dtype sweeps, assert_allclose; the int8 DSC
-kernel is compared EXACTLY). No pallas imports here — these run on any
-backend and define what the kernels mean.
+tests/test_kernels.py (shape/dtype sweeps, assert_allclose). The int8 DSC
+kernel's oracle is ``core.dsc.dsc_block_reference``, compared EXACTLY.
+No pallas imports here — these run on any backend and define what the
+kernels mean.
 """
 
 from __future__ import annotations
@@ -12,46 +13,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-
-# ---------------------------------------------------------------------------
-# fused_dsc oracle — same arithmetic as core.dsc.dsc_block_reference but
-# callable from raw tensors (kernel-shaped inputs, tap-major w_dw).
-# ---------------------------------------------------------------------------
-
-
-def fused_dsc_ref(x_q, w_exp, w_dw9, w_proj, b_exp, b_dw, b_proj,
-                  m_exp, m_dw, m_proj, *, stride, zps, q6):
-    """int8 (H, W, C) -> int8 (H2, W2, N), layer-by-layer, explicit padding."""
-    zp_in, zp_f1, zp_f2, zp_out = zps
-    q6_f1, q6_f2 = q6
-    h, w, cin = x_q.shape
-    cmid = w_exp.shape[1]
-    cout = w_proj.shape[1]
-    s, k = stride, 3
-    h2, w2 = -(-h // s), -(-w // s)
-
-    def requant(acc, m, zp, lo, hi):
-        y = jnp.round(acc.astype(jnp.float32) * m).astype(jnp.int32) + zp
-        return jnp.clip(y, lo, hi).astype(jnp.int8)
-
-    acc = jnp.einsum("hwc,cm->hwm", x_q.astype(jnp.int32),
-                     w_exp.astype(jnp.int32)) + b_exp
-    f1 = requant(acc, m_exp, zp_f1, zp_f1, q6_f1)
-    f1p = jnp.pad(f1, ((1, 1), (1, 1), (0, 0)), constant_values=zp_f1)
-    acc2 = jnp.zeros((h2, w2, cmid), jnp.int32)
-    for dy in range(k):
-        for dx in range(k):
-            win = jax.lax.slice(
-                f1p, (dy, dx, 0),
-                (dy + (h2 - 1) * s + 1, dx + (w2 - 1) * s + 1, cmid),
-                (s, s, 1))
-            acc2 = acc2 + win.astype(jnp.int32) * w_dw9[dy * k + dx].astype(jnp.int32)
-    f2 = requant(acc2 + b_dw, m_dw, zp_f2, zp_f2, q6_f2)
-    acc3 = jnp.einsum("hwm,mn->hwn", f2.astype(jnp.int32),
-                      w_proj.astype(jnp.int32)) + b_proj
-    return requant(acc3, m_proj, zp_out, -128, 127)
-
 
 # ---------------------------------------------------------------------------
 # fused_ffn oracle

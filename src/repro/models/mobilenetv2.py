@@ -12,7 +12,8 @@ plus stride-2 transition blocks, an int8 3x3 stem and a pointwise head —
 a VWW-style classifier (the CFU-Playground deployment model). The whole
 network runs in TFLite int8 arithmetic end-to-end, under any of the
 execution disciplines (v0 reference / v1 pixel-wise / v2 pipelined /
-v3 row-tile / pallas kernel), which are bit-identical by construction.
+v3 row-tile), which are bit-identical by construction. The Pallas kernel
+runs the blocks on the CFU fast path (``cfu.fastpath``).
 """
 
 from __future__ import annotations
@@ -28,7 +29,6 @@ from repro.core import dsc as dsc_mod
 from repro.core import quant
 from repro.core.dsc import DSCBlockSpec, QuantizedDSCParams
 from repro.core.fusion import Schedule, run_block
-from repro.kernels import ops as kops
 
 # (name, cin, cmid, cout, stride) at the paper's feature-map sizes;
 # input feature map is 40x40x8 (stem output).
@@ -170,7 +170,6 @@ def _stem_int8(img_q, p: MobileNetV2Params):
 
 def forward_int8(img, p: MobileNetV2Params,
                  schedule: Schedule = Schedule.V3_INTRA_STAGE,
-                 use_pallas: bool = False,
                  return_quantized: bool = False):
     """Full int8 inference for one image (H, W, 3) float32 -> logits.
 
@@ -182,19 +181,7 @@ def forward_int8(img, p: MobileNetV2Params,
     x = _stem_int8(img_q, p)
 
     for qp in p.blocks:
-        if use_pallas:
-            w_dw9 = qp.w_dw.reshape(9, qp.spec.cmid)
-            y = kops.dsc_block(
-                x, qp.w_exp, w_dw9, qp.w_proj, qp.b_exp, qp.b_dw, qp.b_proj,
-                qp.m_exp, qp.m_dw, qp.m_proj, stride=qp.spec.stride,
-                zps=(qp.qp_in.zero_point, qp.qp_f1.zero_point,
-                     qp.qp_f2.zero_point, qp.qp_out.zero_point),
-                q6=(qp.q6_f1, qp.q6_f2))
-            if qp.spec.has_residual:
-                y = dsc_mod.residual_add_q(y, x, qp)
-            x = y
-        else:
-            x = run_block(x, qp, schedule)
+        x = run_block(x, qp, schedule)
 
     # head 1x1 + ReLU6
     acc = jnp.einsum("hwc,cm->hwm", x.astype(jnp.int32),

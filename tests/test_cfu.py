@@ -958,7 +958,10 @@ _PR3_GOLDEN_FINGERPRINT = {
     ("layer_dram", "dram_bytes"): 1097346,
     ("layer_sram", "cycles"): 10430861.200000247,
     ("layer_sram", "dram_bytes"): 221346,
-    ("logits_q",): [-90, -93],
+    # re-pinned from [-90, -93] when JAX's default random stream moved
+    # (jax_threefry_partitionable): the network's f32 weights changed,
+    # not the arithmetic
+    ("logits_q",): [-61, -128],
 }
 
 

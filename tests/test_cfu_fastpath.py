@@ -153,6 +153,28 @@ def test_t1_chain_every_path_equals_reference(sched):
         np.testing.assert_array_equal(got, want)
 
 
+@pytest.mark.parametrize("sched", schedule_names(include_auto=True))
+def test_dsc_stage_body_choice(sched):
+    """A DSC stage lowers to a ``pallas_call`` exactly when kernel bodies
+    are asked for (``use_pallas``) and its block is lowered fused or
+    rowtile; layer-dram, layer-sram and fused-winograd blocks run the
+    reference body, and with ``use_pallas=False`` no stage holds a
+    kernel. Both chains: with and without expansion."""
+    for specs, params, *_ in (_chain_fixture(), _t1_fixture()):
+        prog = compile_network(specs, HW, HW, sched)
+        for st in fastpath._lift_program(prog):
+            p = params[st.block]
+            x = np.zeros((st.h, st.w, st.cin), np.int8)
+            w = {k: getattr(p, k) for k in fastpath._STAGE_ARRAYS[st.kind]}
+            lowered = prog.meta["block_schedules"][specs[st.block][0]]
+            for use_pallas in (False, True):
+                fn = fastpath._build_stage_fn(st, p, use_pallas)
+                jaxpr = str(jax.make_jaxpr(fn)(x, w))
+                assert ("pallas_call" in jaxpr) == (
+                    use_pallas and lowered in ("fused", "fused-rowtile")), \
+                    f"{sched}: {lowered} block {st.block}, {use_pallas=}"
+
+
 # --- fingerprints + cache ---------------------------------------------------
 
 
@@ -230,16 +252,16 @@ def test_weights_are_traced_not_baked():
 
 
 def test_forced_pallas_stage_bodies_bit_exact_and_separate_cache_key():
-    """On CPU the default trace uses the vectorizable jnp twin; forcing
+    """On CPU the default trace runs the reference body; forcing
     ``use_pallas=True`` must lift through the Pallas kernels instead,
     stay bit-exact against the interpreter (fused AND rowtile lowerings),
     and occupy its own cache slot (the backend is part of the key)."""
     specs, params, x_q = _chain_fixture()
     for sched in ("fused", "fused-rowtile"):
         prog = compile_network(specs, HW, HW, sched)
-        ex_jnp = fastpath.fast_executor(prog, params)
+        ex_ref = fastpath.fast_executor(prog, params)
         ex_pl = fastpath.fast_executor(prog, params, use_pallas=True)
-        assert ex_pl is not ex_jnp and ex_pl.use_pallas
+        assert ex_pl is not ex_ref and ex_pl.use_pallas
         np.testing.assert_array_equal(
             fastpath.run_fast(prog, x_q, params, use_pallas=True),
             run_program(prog, x_q, params), err_msg=sched)

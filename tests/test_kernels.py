@@ -1,4 +1,5 @@
-"""Per-kernel shape/dtype sweeps against the ref.py oracles.
+"""Per-kernel shape/dtype sweeps against their oracles: ``core.dsc``'s
+layer-by-layer reference for the int8 DSC kernel, ref.py for the rest.
 
 Kernels run in interpret mode on this CPU container (TPU is the target).
 The int8 DSC kernel must match EXACTLY; float kernels use dtype-scaled
@@ -13,7 +14,6 @@ import pytest
 from repro.core import dsc, quant
 from repro.core.dsc import DSCBlockSpec
 from repro.kernels import ops, ref
-from repro.kernels.fused_dsc import fused_dsc_pallas
 from repro.kernels.fused_ffn import fused_ffn_pallas
 from repro.kernels.flash_attention import flash_attention
 from repro.models import mobilenetv2 as mnv2
@@ -22,90 +22,51 @@ from repro.models import mobilenetv2 as mnv2
 # --- fused DSC --------------------------------------------------------------
 
 
-@pytest.mark.parametrize("spec,hw,tile_rows", [
-    (DSCBlockSpec(cin=8, cmid=48, cout=8, stride=1), 12, 4),
-    (DSCBlockSpec(cin=8, cmid=48, cout=16, stride=2), 12, 3),
-    (DSCBlockSpec(cin=16, cmid=96, cout=16, stride=1), 10, 2),
-    (DSCBlockSpec(cin=8, cmid=24, cout=8, stride=1), 9, 5),
-    # ragged last tile: tile_rows does not divide h2 (the old fallback
-    # silently degraded to the largest divisor — tile_rows=1 on primes)
-    (DSCBlockSpec(cin=8, cmid=24, cout=8, stride=1), 13, 4),   # h2=13 prime
-    (DSCBlockSpec(cin=8, cmid=24, cout=16, stride=2), 13, 4),  # odd W, h2=7
-    (DSCBlockSpec(cin=8, cmid=24, cout=8, stride=2), 11, 4),   # odd W, h2=6
-    (DSCBlockSpec(cin=8, cmid=24, cout=8, stride=1), 7, 16),   # tile > h2
-])
-def test_fused_dsc_exact_vs_oracle(spec, hw, tile_rows):
-    key = jax.random.PRNGKey(0)
-    p32 = dsc.init_dsc_block_f32(key, spec)
-    calib = np.asarray(jax.random.normal(jax.random.PRNGKey(1),
-                                         (hw, hw, spec.cin)))
-    qp = dsc.quantize_dsc_block(p32, spec, calib)
-    x_q = jnp.asarray(quant.quantize(calib, qp.qp_in))
-    w_dw9 = qp.w_dw.reshape(9, spec.cmid)
-    zps = (qp.qp_in.zero_point, qp.qp_f1.zero_point,
-           qp.qp_f2.zero_point, qp.qp_out.zero_point)
-    got = fused_dsc_pallas(x_q, qp.w_exp, w_dw9, qp.w_proj, qp.b_exp,
-                           qp.b_dw, qp.b_proj, qp.m_exp, qp.m_dw, qp.m_proj,
-                           stride=spec.stride, zps=zps,
-                           q6=(qp.q6_f1, qp.q6_f2), tile_rows=tile_rows,
-                           interpret=True)
-    want = ref.fused_dsc_ref(x_q, qp.w_exp, w_dw9, qp.w_proj, qp.b_exp,
-                             qp.b_dw, qp.b_proj, qp.m_exp, qp.m_dw,
-                             qp.m_proj, stride=spec.stride, zps=zps,
-                             q6=(qp.q6_f1, qp.q6_f2))
-    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-
-
 VWW_BLOCKS = [(name, spec, hw) for (name, spec), hw
               in zip(mnv2.block_specs(), mnv2.block_input_hw(80))]
 
+# (spec, map size, tile_rows, seed): the block's weights come from PRNGKey
+# seed, its input from seed + 1
+DSC_CASES = [
+    (DSCBlockSpec(cin=8, cmid=48, cout=8, stride=1), 12, 4, 0),
+    (DSCBlockSpec(cin=8, cmid=48, cout=16, stride=2), 12, 3, 0),
+    (DSCBlockSpec(cin=16, cmid=96, cout=16, stride=1), 10, 2, 0),
+    (DSCBlockSpec(cin=8, cmid=24, cout=8, stride=1), 9, 5, 0),
+    # ragged last tile: tile_rows does not divide h2 (the old fallback
+    # silently degraded to the largest divisor — tile_rows=1 on primes)
+    (DSCBlockSpec(cin=8, cmid=24, cout=8, stride=1), 13, 4, 0),  # h2=13
+    (DSCBlockSpec(cin=8, cmid=24, cout=16, stride=2), 13, 4, 0),  # odd W
+    (DSCBlockSpec(cin=8, cmid=24, cout=8, stride=2), 11, 4, 0),   # h2=6
+    (DSCBlockSpec(cin=8, cmid=24, cout=8, stride=1), 7, 16, 0),   # tile>h2
+] + [
+    # every VWW block at its real widths and map size (stride 2 and the
+    # 10x10 / 5x5 maps included)
+    (spec, hw, 4, 3) for _, spec, hw in VWW_BLOCKS
+] + [
+    # blocks without expansion (t=1), the same kernel body with the
+    # expansion left out: F1 is the input, padded with its zero point
+    (DSCBlockSpec(cin=8, cmid=8, cout=8, stride=1), 9, 4, 5),    # residual
+    (DSCBlockSpec(cin=8, cmid=8, cout=16, stride=2), 11, 4, 5),  # h2=6
+    (DSCBlockSpec(cin=16, cmid=16, cout=8, stride=1), 7, 3, 5),  # ragged
+    (DSCBlockSpec(cin=136, cmid=136, cout=24, stride=2), 9, 2, 5),  # 2 chunks
+]
+DSC_IDS = ([f"sweep{i}" for i in range(8)]
+           + [f"vww-{name}" for name, _, _ in VWW_BLOCKS]
+           + [f"t1-{i}" for i in range(4)])
 
-@pytest.mark.parametrize("name,spec,hw", VWW_BLOCKS,
-                         ids=[b[0] for b in VWW_BLOCKS])
-def test_fused_dsc_vww_blocks_exact_vs_reference(name, spec, hw):
-    """Every VWW block at its real widths and map size (stride 2 and the
-    10x10 / 5x5 maps included), held to the layer-by-layer reference."""
-    p32 = dsc.init_dsc_block_f32(jax.random.PRNGKey(3), spec)
-    calib = np.asarray(jax.random.normal(jax.random.PRNGKey(4),
+
+@pytest.mark.parametrize("spec,hw,tile_rows,seed", DSC_CASES, ids=DSC_IDS)
+def test_dsc_kernel_exact_vs_reference(spec, hw, tile_rows, seed):
+    """The DSC kernel (``dsc_block``, or ``dw_block`` without expansion)
+    plus the residual add where the block has one, through
+    ``ops.dsc_block_q`` in interpret mode, bit-exact to the layer-by-layer
+    reference."""
+    p32 = dsc.init_dsc_block_f32(jax.random.PRNGKey(seed), spec)
+    calib = np.asarray(jax.random.normal(jax.random.PRNGKey(seed + 1),
                                          (hw, hw, spec.cin)))
     qp = dsc.quantize_dsc_block(p32, spec, calib)
     x_q = jnp.asarray(quant.quantize(calib, qp.qp_in))
-    zps = (qp.qp_in.zero_point, qp.qp_f1.zero_point,
-           qp.qp_f2.zero_point, qp.qp_out.zero_point)
-    got = fused_dsc_pallas(x_q, qp.w_exp, qp.w_dw.reshape(9, spec.cmid),
-                           qp.w_proj, qp.b_exp, qp.b_dw, qp.b_proj, qp.m_exp,
-                           qp.m_dw, qp.m_proj, stride=spec.stride, zps=zps,
-                           q6=(qp.q6_f1, qp.q6_f2), tile_rows=4,
-                           interpret=True)
-    if spec.has_residual:
-        got = dsc.residual_add_q(got, x_q, qp)
-    np.testing.assert_array_equal(np.asarray(got),
-                                  np.asarray(dsc.dsc_block_reference(x_q, qp)))
-
-
-@pytest.mark.parametrize("spec,hw,tile_rows", [
-    (DSCBlockSpec(cin=8, cmid=8, cout=8, stride=1), 9, 4),     # residual
-    (DSCBlockSpec(cin=8, cmid=8, cout=16, stride=2), 11, 4),   # odd W, h2=6
-    (DSCBlockSpec(cin=16, cmid=16, cout=8, stride=1), 7, 3),   # h2=7 ragged
-    (DSCBlockSpec(cin=136, cmid=136, cout=24, stride=2), 9, 2),  # 2 chunks
-])
-def test_dw_block_exact_vs_reference(spec, hw, tile_rows):
-    """A block without expansion (t=1) through its own entry, the same
-    kernel body with the expansion left out: F1 is the input, padded with
-    the input's zero point, bit-exact to the layer-by-layer reference."""
-    p32 = dsc.init_dsc_block_f32(jax.random.PRNGKey(5), spec)
-    calib = np.asarray(jax.random.normal(jax.random.PRNGKey(6),
-                                         (hw, hw, spec.cin)))
-    qp = dsc.quantize_dsc_block(p32, spec, calib)
-    x_q = jnp.asarray(quant.quantize(calib, qp.qp_in))
-    got = ops.dw_block(x_q, qp.w_dw.reshape(9, spec.cmid), qp.w_proj,
-                       qp.b_dw, qp.b_proj, qp.m_dw, qp.m_proj,
-                       stride=spec.stride,
-                       zps=(qp.qp_in.zero_point, qp.qp_f2.zero_point,
-                            qp.qp_out.zero_point),
-                       q6=qp.q6_f2, tile_rows=tile_rows, interpret=True)
-    if spec.has_residual:
-        got = dsc.residual_add_q(got, x_q, qp)
+    got = ops.dsc_block_q(x_q, qp, tile_rows=tile_rows)
     np.testing.assert_array_equal(np.asarray(got),
                                   np.asarray(dsc.dsc_block_reference(x_q, qp)))
 

@@ -4,6 +4,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro.core import quant
 from repro.core.fusion import Schedule
 from repro.models import mobilenetv2 as mnv2
 
@@ -38,10 +39,17 @@ def test_all_schedules_end_to_end_identical(net, img):
 
 
 def test_pallas_kernel_end_to_end_identical(net, img):
-    ref = np.asarray(mnv2.forward_int8(img, net,
-                                       schedule=Schedule.V0_LAYER_BY_LAYER))
-    out = np.asarray(mnv2.forward_int8(img, net, use_pallas=True))
-    np.testing.assert_array_equal(ref, out)
+    """The compiled 80 px VWW program on the fast path with its DSC blocks
+    in the Pallas kernel gives the reference's int8 logits."""
+    from repro.cfu.compiler import compile_vww_network
+    from repro.cfu.fastpath import fast_executor
+    from repro.cfu.network import vww_cfu_params
+    params = vww_cfu_params(net)
+    prog = compile_vww_network(mnv2.block_specs(), 80, "fused")
+    ex = fast_executor(prog, params, use_pallas=True)
+    out = ex(np.asarray(quant.quantize(img, net.qp_img)), params)
+    ref = np.asarray(mnv2.forward_int8(img, net, return_quantized=True))
+    np.testing.assert_array_equal(out, ref)
 
 
 def test_batched_inference(net):
