@@ -304,8 +304,12 @@ def _build_stage_fn(stage: _Stage, p, use_pallas: bool):
 
         def stem_fn(x, w):
             # im2col: 9 strided taps concatenated on the channel axis, then
-            # ONE (H2*W2, 9*Cin) GEMM — on CPU this beats the generic
-            # strided conv by >2x at the stem's tiny channel counts
+            # one GEMM that keeps (H2, W2) as free dimensions. A reshape to
+            # (H2*W2, 9*Cin) and back makes XLA:TPU put the pixels of the
+            # int32 accumulator in the lanes and relay it out channel-minor
+            # before a separate requantization; kept 3-D, the bias, requant
+            # and int8 convert fuse into the GEMM's output, so the int8 map
+            # is all the stem writes
             xp = jnp.pad(x, ((1, 1), (1, 1), (0, 0)),
                          constant_values=zp_in)
             h2, w2 = -(-x.shape[0] // s), -(-x.shape[1] // s)
@@ -315,8 +319,7 @@ def _build_stage_fn(stage: _Stage, p, use_pallas: bool):
                 (s, s, 1)) for dy in range(3) for dx in range(3)]
             patches = jnp.concatenate(cols, axis=-1).astype(conv_dt)
             wf = w["w_conv"].reshape(9 * cin, cout).astype(conv_dt)
-            acc = (patches.reshape(h2 * w2, 9 * cin) @ wf
-                   ).astype(jnp.int32).reshape(h2, w2, cout)
+            acc = jnp.einsum("hwk,kc->hwc", patches, wf).astype(jnp.int32)
             return quant.requantize(acc + w["b_conv"], w["m_exp"], zp_f1,
                                     relu=True, relu6_max_q=q6)
         return stem_fn

@@ -175,6 +175,57 @@ def test_dsc_stage_body_choice(sched):
                     f"{sched}: {lowered} block {st.block}, {use_pallas=}"
 
 
+@pytest.mark.parametrize("hw,cout", [(80, 8), (224, 32)],
+                         ids=["80x80x3-8", "224x224x3-32"])
+def test_stem_stage_bit_exact_vs_conv_stem(hw, cout):
+    """The stem stage lifted from the VWW or the MobileNetV2 1.0 program
+    (im2col taps, one GEMM over the free (H2, W2) dimensions, requant
+    behind it) equals the model's ``lax.conv`` stem bit for bit, with
+    outputs on both clamps: the zero point (ReLU) and the ReLU6 cap."""
+    import json
+    import pathlib
+    import types
+
+    from repro.cfu.compiler import compile_vww_network
+    from repro.cfu.network import CFUStemParams
+    from repro.models import mobilenetv2 as mnv2
+
+    if hw == 80:
+        specs = mnv2.block_specs()
+    else:
+        cfg = json.loads((pathlib.Path(__file__).resolve().parents[1] /
+                          "chipbench/configs/mnv2-224-fused.json").read_text())
+        specs = [(name, DSCBlockSpec(ci, cm, co, s))
+                 for name, ci, cm, co, s in cfg["blocks"]]
+    prog = compile_vww_network(specs, hw, "fused")
+    stage = fastpath._lift_program(prog)[0]
+    assert (stage.kind, stage.cin, stage.cout, stage.stride) == (
+        "stem", 3, cout, 2)
+
+    rng = np.random.default_rng(hw)
+    qp_img, qp_stem = quant.QParams(1 / 128, 3), quant.QParams(0.05, -20)
+    q6 = quant.relu6_max_q(qp_stem)
+    p = CFUStemParams(
+        rng.integers(-128, 128, (3, 3, 3, cout), dtype=np.int8),
+        rng.integers(-5000, 5000, cout, dtype=np.int32),
+        rng.uniform(1e-3, 1e-2, cout).astype(np.float32),
+        qp_img, qp_stem, q6)
+    imgs = rng.integers(-128, 128, (2, hw, hw, 3), dtype=np.int8)
+    w = {k: getattr(p, k) for k in fastpath._STAGE_ARRAYS["stem"]}
+    got = np.asarray(jax.vmap(
+        fastpath._build_stage_fn(stage, p, use_pallas=False),
+        in_axes=(0, None))(imgs, w))
+
+    ref_p = types.SimpleNamespace(stem_w=p.w_conv, stem_b=p.b_conv,
+                                  stem_m=p.m_exp, qp_img=qp_img,
+                                  qp_stem=qp_stem)
+    want = np.asarray(jax.vmap(lambda im: mnv2._stem_int8(im, ref_p))(imgs))
+    assert got.shape == (2, -(-hw // 2), -(-hw // 2), cout)
+    np.testing.assert_array_equal(got, want)
+    assert (want == qp_stem.zero_point).any() and (want == q6).any()
+    assert ((want > qp_stem.zero_point) & (want < q6)).any()
+
+
 # --- fingerprints + cache ---------------------------------------------------
 
 

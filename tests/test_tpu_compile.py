@@ -22,6 +22,7 @@ test worker imports this file.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -153,7 +154,9 @@ def _zero_params(cfg):
     return specs, params
 
 
-def test_mnv2_224_chain_compiles_for_v5e(one_chip, compiled_kernels):
+def _mnv2_224_compiled(one_chip, batch):
+    """The ``mnv2-224-fused`` chain compiled for one described v5e chip at
+    ``batch`` images, as HLO text; and the configuration."""
     import json
     import pathlib
     from repro.cfu.compiler import compile_vww_network
@@ -171,10 +174,79 @@ def test_mnv2_224_chain_compiles_for_v5e(one_chip, compiled_kernels):
         a = np.asarray(a)
         return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
 
-    x = jax.ShapeDtypeStruct((8, 224, 224, 3), jnp.int8, sharding=one_chip)
+    x = jax.ShapeDtypeStruct((batch, 224, 224, 3), jnp.int8,
+                             sharding=one_chip)
     weights = jax.tree.map(sds, ex.weights_of(params))
-    text = ex.jitted.lower(x, weights).compile().as_text()
+    return ex.jitted.lower(x, weights).compile().as_text(), cfg
+
+
+def test_mnv2_224_chain_compiles_for_v5e(one_chip, compiled_kernels):
+    text, cfg = _mnv2_224_compiled(one_chip, 8)
     assert text.count("tpu_custom_call") >= len(cfg["blocks"])
+
+
+def _hlo_computations(text):
+    """Computation name -> its instruction lines, from compiled HLO text;
+    the entry computation also under ``"ENTRY"``."""
+    comps, body = {}, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            body = comps.setdefault(head.group(2), [])
+            if head.group(1):
+                comps["ENTRY"] = body
+        elif line == "}":
+            body = None
+        elif body is not None:
+            body.append(line)
+    return comps
+
+
+_HLO_INSTR = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = (.*?) ([a-z][\w\-]*)\((.*)$")
+
+
+def test_mnv2_224_stem_requantizes_inside_its_gemm(one_chip,
+                                                   compiled_kernels):
+    """At the benchmark's 128-image chunk the stem's int32 accumulator
+    never reaches HBM: no entry instruction outputs a 32-bit array of the
+    stem map's size (it would be the GEMM's raw output or a relayout of
+    it), and the first kernel reads the int8 map straight from the fusion
+    that holds the stem's GEMM."""
+    batch = 128
+    text, cfg = _mnv2_224_compiled(one_chip, batch)
+    comps = _hlo_computations(text)
+    stem_elems = batch * 112 * 112 * cfg["blocks"][0][1]
+    instrs = {}
+    for line in comps["ENTRY"]:
+        m = _HLO_INSTR.match(line)
+        if not m:
+            continue
+        name, out_type, op, rest = m.groups()
+        instrs[name] = (op, rest)
+        for dt, dims in re.findall(r"\b([suf]32)\[([\d,]*)\]", out_type):
+            n = int(np.prod([int(d) for d in dims.split(",") if d]))
+            assert n != stem_elems, f"%{name} outputs {dt}[{dims}]"
+
+    first_kernel = next(rest for op, rest in instrs.values()
+                        if op == "custom-call"
+                        and 'custom_call_target="tpu_custom_call"' in rest)
+    operand = re.match(r"%([\w.\-]+)", first_kernel).group(1)
+    op, rest = instrs[operand]
+    assert op == "fusion", f"the first kernel reads %{operand}, a {op}"
+
+    def holds_gemm(comp):
+        for line in comps[comp]:
+            m = _HLO_INSTR.match(line)
+            if m and m.group(3) in ("dot", "convolution"):
+                return True
+            if any(holds_gemm(c) for c in
+                   re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", line)):
+                return True
+        return False
+
+    called = re.search(r"calls=%([\w.\-]+)", rest).group(1)
+    assert holds_gemm(called), f"%{operand} ({called}) holds no dot"
 
 
 @pytest.fixture(scope="module")
